@@ -15,8 +15,10 @@ Cache keys are salted with the KERNEL VERSION and the BACKEND:
 so a committed cache from one backend can never serve block choices on
 another, and a kernel rewrite (bump :data:`KERNEL_VERSIONS`) orphans every
 stale entry instead of silently reusing blocks tuned for the old grid.  The
-default cache file is per-backend too (``~/.cache/repro/autotune.<backend>
-.json``); ``REPRO_AUTOTUNE_CACHE`` overrides the path wholesale.  Lookup is
+default cache file is per-backend too, inside the checkout
+(``results/autotune/<backend>.json`` — the file the offline sweep writes and
+the repository commits); ``REPRO_AUTOTUNE_CACHE`` overrides the path
+wholesale.  Lookup is
 CACHE-FIRST on every backend — a warmed cache serves its block choice even
 where tuning itself is disabled — and every candidate actually timed bumps
 :func:`tuning_probe_count`, so tests can assert a warmed trace performs
@@ -51,6 +53,8 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 
+from ..checkout import ROOT
+
 Blocks = Tuple[int, int, int]
 
 _LOCK = threading.Lock()
@@ -82,13 +86,17 @@ def cache_key(kernel: str, M: int, N: int, K: int,
     return f"{kernel}@v{v}:{M}x{N}x{K}:{b}"
 
 
-def default_cache_path(backend: Optional[str] = None) -> str:
-    env = os.environ.get("REPRO_AUTOTUNE_CACHE")
-    if env:
-        return env
+def committed_cache_path(backend: Optional[str] = None) -> str:
+    """``<checkout>/results/autotune/<backend>.json``: the per-backend cache
+    the offline sweep writes and the repository commits."""
     b = backend or jax.default_backend()
-    return os.path.join(os.path.expanduser("~"), ".cache", "repro",
-                        f"autotune.{b}.json")
+    return str(ROOT / "results" / "autotune" / f"{b}.json")
+
+
+def default_cache_path(backend: Optional[str] = None) -> str:
+    """``REPRO_AUTOTUNE_CACHE`` if set, else :func:`committed_cache_path`."""
+    return os.environ.get("REPRO_AUTOTUNE_CACHE") or \
+        committed_cache_path(backend)
 
 
 def heuristic_block(m: int, cap: int = 128) -> int:
@@ -344,7 +352,8 @@ def blocks_for(kernel: str, M: int, N: int, K: int, *,
                cache_path: Optional[str] = None,
                candidates: Optional[Sequence[Blocks]] = None,
                force_tune: bool = False,
-               meta: Optional[dict] = None) -> Blocks:
+               meta: Optional[dict] = None,
+               operands: Sequence = ()) -> Blocks:
     """Resolve the block triple for one kernel launch.
 
     Lookup order: persistent cache (warmed offline by the sweep, or by a
@@ -356,12 +365,15 @@ def blocks_for(kernel: str, M: int, N: int, K: int, *,
     back to the heuristic so the interpret path stays cheap and
     deterministic.  Every call is visible to :func:`record_requests` (the
     offline sweep's shape discovery), including calls made while tracing.
+    ``operands``: the launch's array arguments; if any is a tracer the call
+    is under a jit/vmap trace and nothing is timed or persisted.
     """
     _record(kernel, M, N, K, tunable=True, meta=meta)
     fallback = heuristic_blocks(M, N, K)
     key = cache_key(kernel, M, N, K)
     cache = _shared_cache(cache_path)
-    if not jax.core.trace_state_clean():
+    if any(isinstance(a, jax.core.Tracer)
+           for a in jax.tree_util.tree_leaves(operands)):
         # inside a jit/vmap trace the bench closure holds tracers:
         # "timing" it measures Python tracing, not the kernel.  Use the
         # cache if warm, else the heuristic — and never persist from here.

@@ -17,24 +17,14 @@ is tiled in blocks of ``bh`` rows.  Each input block carries its halo: the
 ``bh`` output rows of tile ``t`` consume input rows
 ``[t*bh*stride, t*bh*stride + (bh-1)*stride + kh)``, so consecutive input
 blocks OVERLAP by ``kh - stride`` rows.  Overlap is expressed with
-``pl.Unblocked`` element-offset indexing (a blocked BlockSpec can only step
+``pl.Element`` element-offset indexing (a blocked BlockSpec can only step
 by whole blocks); the per-block VMEM footprint is bounded by the tile, not
 the feature map, so arbitrary-resolution maps (R256/R384/R512, detection
-sizes) run the packed-w4 kernel — the old whole-map VMEM guard is gone.
+sizes) run the packed-w4 kernel.
 
-Two padding modes:
-
-* ``fuse_pad=False`` — the wrapper materializes XLA SAME padding once
-  (asymmetric for even windows under stride, matching
-  ``lax.conv_general_dilated``) and the kernel body only sees padded tiles.
-* ``fuse_pad=True`` — the *unpadded* map is handed to ``pallas_call`` and
-  SAME padding fuses into the kernel: ``pl.Unblocked(padding=...)`` extends
-  the logical index space (the DMA engine serves the halo; the pad region
-  is UNINITIALIZED, not zero) and the body masks every tap against the real
-  [0,H)x[0,W) bounds with iota predicates — selects, not multiplies, so
-  uninitialized pad bytes (even NaN) never reach the accumulator.  This is
-  the stride-2 MBConv stage-entry path: downsamplers no longer re-pad
-  (an HBM round-trip of the full map) outside the kernel.
+The wrapper materializes XLA SAME padding once (asymmetric for even windows
+under stride, matching ``lax.conv_general_dilated``), so the kernel body only
+sees padded tiles and every tap is a plain strided read of the VMEM block.
 """
 from __future__ import annotations
 
@@ -44,8 +34,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from .compat import CompilerParams
+from jax.experimental.pallas import tpu as pltpu
 
 
 def same_padding(size: int, k: int, stride: int) -> Tuple[int, int]:
@@ -58,67 +47,47 @@ def same_padding(size: int, k: int, stride: int) -> Tuple[int, int]:
 
 def _decode_w4(wp_ref, scale_ref, zp_ref, KH: int, KW: int) -> jax.Array:
     """Unpack the (kh*kw, bc/2) nibble tile to (kh*kw, bc) f32 weights —
-    once per grid step, in VMEM."""
-    lo = (wp_ref[...] & 0x0F).astype(jnp.float32)
-    hi = ((wp_ref[...] >> 4) & 0x0F).astype(jnp.float32)
+    once per grid step, in VMEM (through int32: the chip has no direct
+    uint8 -> f32 convert)."""
+    p = wp_ref[0].astype(jnp.int32)
+    lo = p & 0x0F
+    hi = (p >> 4) & 0x0F
     q = jnp.stack([lo, hi], axis=-1).reshape(KH * KW, -1)
-    return (q - zp_ref[...]) * scale_ref[...]
+    return (q.astype(jnp.float32) - zp_ref[0]) * scale_ref[0]
 
 
 def _kernel(x_ref, wp_ref, scale_ref, zp_ref, o_ref, *, KH: int, KW: int,
             BH: int, WO: int, stride: int):
-    """Pre-padded variant: the block is SAME-padded rows, taps are pure
-    strided slices."""
+    """The block is SAME-padded rows; tap (i, j) of every output pixel in
+    the tile is one strided read of the block."""
     w = _decode_w4(wp_ref, scale_ref, zp_ref, KH, KW)
-    x = x_ref[0].astype(jnp.float32)  # (BH_in, WI, bc), SAME-padded
-    acc = jnp.zeros((BH, WO, x.shape[-1]), jnp.float32)
-    s = stride
+    acc = jnp.zeros((BH, WO, w.shape[-1]), jnp.float32)
     for i in range(KH):
         for j in range(KW):
-            tap = x[i:i + (BH - 1) * s + 1:s, j:j + (WO - 1) * s + 1:s]
-            acc = acc + tap * w[KW * i + j]
-    o_ref[0] = acc
-
-
-def _kernel_fused_pad(x_ref, wp_ref, scale_ref, zp_ref, o_ref, *, KH: int,
-                      KW: int, BH: int, WO: int, stride: int, H: int, W: int,
-                      ph_lo: int, pw_lo: int):
-    """Fused-pad variant: the block indexes the logically padded map (pad
-    region uninitialized) and every tap is masked against the real bounds.
-    Padded-coordinate input row of output row r, tap i:  r*stride + i;
-    the unpadded row is that minus ph_lo — valid iff in [0, H)."""
-    t = pl.program_id(1)
-    w = _decode_w4(wp_ref, scale_ref, zp_ref, KH, KW)
-    x = x_ref[0].astype(jnp.float32)  # (BH_in, WI, bc), halo'd + pad garbage
-    acc = jnp.zeros((BH, WO, x.shape[-1]), jnp.float32)
-    s = stride
-    row = jax.lax.broadcasted_iota(jnp.int32, (BH, WO), 0)  # out row in tile
-    col = jax.lax.broadcasted_iota(jnp.int32, (BH, WO), 1)  # out col
-    for i in range(KH):
-        for j in range(KW):
-            tap = x[i:i + (BH - 1) * s + 1:s, j:j + (WO - 1) * s + 1:s]
-            gr = (t * BH + row) * s + i - ph_lo  # unpadded input row
-            gc = col * s + j - pw_lo             # unpadded input col
-            ok = (gr >= 0) & (gr < H) & (gc >= 0) & (gc < W)
-            acc = acc + jnp.where(ok[..., None], tap, 0.0) * w[KW * i + j]
+            if stride == 1:
+                tap = x_ref[0, pl.ds(i, BH), pl.ds(j, WO), :]
+            else:
+                tap = x_ref[0, pl.ds(i, BH, stride=stride),
+                            pl.ds(j, WO, stride=stride), :]
+            acc = acc + tap.astype(jnp.float32) * w[KW * i + j]
     o_ref[0] = acc
 
 
 def dwconv_w4(x: jax.Array, packed: jax.Array, scale: jax.Array,
               zero_point: jax.Array, *, kh: int = 3, kw: int = 3,
               stride: int = 1, bh: Optional[int] = None, bc: int = 128,
-              fuse_pad: bool = False, interpret: bool = False) -> jax.Array:
+              interpret: bool = False) -> jax.Array:
     """x (B,H,W,C) (unpadded); packed (kh*kw, C/2) uint8; scale/zp (C,) f32.
 
     Returns (B,HO,WO,C) f32 — depthwise kh x kw, SAME padding, stride >= 1.
     ``bh``: output rows per H-tile (None = whole map in one tile); ``bc``:
-    channels per tile.  ``fuse_pad``: SAME-pad inside the kernel instead of
-    materializing a padded copy (see module docstring).
+    channels per tile — all C, or a multiple of 128 lanes.
     """
     B, H, W, C = x.shape
     assert packed.shape[0] == kh * kw, (packed.shape, kh, kw)
     bc = min(bc, C)
     assert C % bc == 0 and bc % 2 == 0
+    assert bc == C or bc % 128 == 0, (bc, C)  # lane-dim tiling rule
     ph = same_padding(H, kh, stride)
     pw = same_padding(W, kw, stride)
     HO = -(-H // stride)
@@ -129,44 +98,35 @@ def dwconv_w4(x: jax.Array, packed: jax.Array, scale: jax.Array,
     bh_in = (bh - 1) * stride + kh        # input rows read per tile (halo'd)
     WI = W + pw[0] + pw[1]
     # rows the LAST tile reads, in padded coordinates; pad the bottom so
-    # every unblocked read stays in bounds (zero rows only ever feed output
-    # rows >= HO, which are sliced away)
+    # every element-offset read stays in bounds (zero rows only ever feed
+    # output rows >= HO, which are sliced away)
     hi_need = (T - 1) * step + bh_in
-    grid = (B, T, C // bc)
-    if fuse_pad:
-        pad_bot = max(hi_need - ph[0] - H, 0)
-        in_spec = pl.BlockSpec(
-            (1, bh_in, WI, bc), lambda b, t, c: (b, t * step, 0, c * bc),
-            indexing_mode=pl.Unblocked(
-                ((0, 0), (ph[0], pad_bot), pw, (0, 0))))
-        body = functools.partial(_kernel_fused_pad, KH=kh, KW=kw, BH=bh,
-                                 WO=WO, stride=stride, H=H, W=W,
-                                 ph_lo=ph[0], pw_lo=pw[0])
-        operand = x
-    else:
-        xp = jnp.pad(x, ((0, 0), ph, pw, (0, 0)))
-        extra = hi_need - xp.shape[1]
-        if extra > 0:
-            xp = jnp.pad(xp, ((0, 0), (0, extra), (0, 0), (0, 0)))
-        in_spec = pl.BlockSpec(
-            (1, bh_in, WI, bc), lambda b, t, c: (b, t * step, 0, c * bc),
-            indexing_mode=pl.unblocked)
-        body = functools.partial(_kernel, KH=kh, KW=kw, BH=bh, WO=WO,
-                                 stride=stride)
-        operand = xp
+    xp = jnp.pad(x, ((0, 0), (ph[0], max(ph[1], hi_need - ph[0] - H)), pw,
+                     (0, 0)))
+    nc = C // bc
+    grid = (B, T, nc)
+    # a single channel block gets a literal 0 lane offset: Mosaic must
+    # prove every lane offset is 128-aligned, and c * bc is not when bc < 128
+    in_spec = pl.BlockSpec(
+        (pl.Element(1), pl.Element(bh_in), pl.Element(WI), pl.Element(bc)),
+        lambda b, t, c: (b, t * step, 0, 0 if nc == 1 else c * bc))
+    # per-channel-block weight slabs: (C/bc, taps, bc/2) keeps every block's
+    # last two dims whole, so any bc is legal for the packed nibbles
+    wp = packed.reshape(kh * kw, nc, bc // 2).transpose(1, 0, 2)
     y = pl.pallas_call(
-        body,
+        functools.partial(_kernel, KH=kh, KW=kw, BH=bh, WO=WO, stride=stride),
         grid=grid,
         in_specs=[
             in_spec,
-            pl.BlockSpec((kh * kw, bc // 2), lambda b, t, c: (0, c)),
-            pl.BlockSpec((1, bc), lambda b, t, c: (0, c)),
-            pl.BlockSpec((1, bc), lambda b, t, c: (0, c)),
+            pl.BlockSpec((1, kh * kw, bc // 2), lambda b, t, c: (c, 0, 0)),
+            pl.BlockSpec((1, 1, bc), lambda b, t, c: (c, 0, 0)),
+            pl.BlockSpec((1, 1, bc), lambda b, t, c: (c, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, bh, WO, bc), lambda b, t, c: (b, t, 0, c)),
         out_shape=jax.ShapeDtypeStruct((B, T * bh, WO, C), jnp.float32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel")),
         interpret=interpret,
-    )(operand, packed, scale.reshape(1, -1), zero_point.reshape(1, -1))
+        name="dwconv_w4",
+    )(xp, wp, scale.reshape(nc, 1, bc), zero_point.reshape(nc, 1, bc))
     return y[:, :HO]

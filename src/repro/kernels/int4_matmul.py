@@ -17,16 +17,17 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import CompilerParams
 
 
 def _unpack_nibbles(packed: jax.Array) -> jax.Array:
-    """(bk, bn/2) uint8 -> (bk, bn) f32 codes in 0..15 (even idx = low)."""
-    lo = (packed & 0x0F).astype(jnp.float32)
-    hi = ((packed >> 4) & 0x0F).astype(jnp.float32)
+    """(bk, bn/2) uint8 -> (bk, bn) f32 codes in 0..15 (even idx = low),
+    through int32: the chip has no direct uint8 -> f32 convert."""
+    p = packed.astype(jnp.int32)
+    lo = p & 0x0F
+    hi = (p >> 4) & 0x0F
     bk, half = packed.shape
     out = jnp.stack([lo, hi], axis=-1)  # (bk, bn/2, 2)
-    return out.reshape(bk, 2 * half)
+    return out.reshape(bk, 2 * half).astype(jnp.float32)
 
 
 def _kernel(x_ref, wp_ref, wscale_ref, zp_ref, o_ref, acc_ref, *, nk: int):
@@ -48,7 +49,11 @@ def int4_matmul(x: jax.Array, packed: jax.Array, scale: jax.Array,
                 zero_point: jax.Array,
                 *, bm: int = 128, bn: int = 128, bk: int = 128,
                 interpret: bool = False) -> jax.Array:
-    """x (M,K) f32/bf16; packed (K,N/2) uint8; scale/zp (N,) -> (M,N) f32."""
+    """x (M,K) f32/bf16; packed (K,N/2) uint8; scale/zp (N,) -> (M,N) f32.
+
+    Shapes must be pre-padded to block multiples, with blocks the TPU can
+    tile: the packed block's bn/2 lanes a multiple of 128 or all of N/2
+    (ops.py does both)."""
     M, K = x.shape
     N = packed.shape[1] * 2
     nk = K // bk
@@ -65,7 +70,8 @@ def int4_matmul(x: jax.Array, packed: jax.Array, scale: jax.Array,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="int4_matmul",
     )(x, packed, scale.reshape(1, -1), zero_point.reshape(1, -1))

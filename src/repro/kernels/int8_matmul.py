@@ -23,7 +23,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..core.quant import quantize_act
-from .compat import CompilerParams
 
 
 def _kernel(x_ref, w_ref, ascale_ref, wscale_ref, zp_ref, o_ref,
@@ -78,8 +77,9 @@ def int8_matmul(x: jax.Array, wq: jax.Array, act_scale: jax.Array,
             pltpu.VMEM((bm, bn), jnp.int32),
             pltpu.VMEM((bm, 1), jnp.int32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="int8_matmul",
     )(x, wq, act_scale.reshape(1, 1), scale.reshape(1, -1),
       zero_point.reshape(1, -1))

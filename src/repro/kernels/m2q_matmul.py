@@ -42,7 +42,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..core.quant import quantize_act
 from .apot_matmul import decode_apot_tile
-from .compat import CompilerParams
 
 
 def _kernel(x_ref, p_ref, uscale_ref, uzp_ref, ascale_ref, act_scale_ref,
@@ -107,8 +106,9 @@ def m2q_matmul(x: jax.Array, act_scale: jax.Array, payload: jax.Array,
             pltpu.VMEM((bm, 1), jnp.int32),
             pltpu.VMEM((bm, bn), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="m2q_matmul",
     )(x, payload, u_scale.reshape(1, -1), u_zp.reshape(1, -1),
       a_scale.reshape(1, -1), act_scale.reshape(1, 1))

@@ -49,6 +49,7 @@ import contextvars
 import dataclasses
 import math
 import os
+import warnings
 from functools import partial
 from typing import Optional, Tuple
 
@@ -142,6 +143,64 @@ def dispatch(config: Optional[DispatchConfig] = None, *,
         _DISPATCH_SCOPE.reset(token)
 
 
+_KERNEL_MESH: contextvars.ContextVar = contextvars.ContextVar(
+    "repro_kernel_mesh", default=None)
+
+
+@contextlib.contextmanager
+def kernel_mesh(mesh):
+    """Scope the device mesh that kernel launches traced inside it run on.
+
+    Mosaic kernels cannot be partitioned by XLA's SPMD partitioner, so under
+    a mesh every launch is wrapped in a ``shard_map``: batch dims split over
+    the ``data`` axis and attention heads over ``model`` (where they divide
+    evenly); everything else — weights included — is replicated, so a
+    model-sharded weight is gathered before the launch.  ``None`` (no mesh)
+    launches the kernel directly.  The serving engines enter this scope
+    around their traces when built with ``mesh=``."""
+    token = _KERNEL_MESH.set(mesh)
+    try:
+        yield
+    finally:
+        _KERNEL_MESH.reset(token)
+
+
+def _on_mesh(mesh, fn, args, dims, out_dims):
+    """``fn(*args)``, per device of ``mesh`` (the :func:`kernel_mesh` scope,
+    read by the op wrappers — outside the jitted cores, whose trace cache
+    does not see context variables — and passed down as a static arg).
+
+    ``dims`` names, per argument, the mesh axis of each array dim (None:
+    replicated); an axis is used only if it has more than one device and
+    divides every dim it names.  ``out_dims`` does the same for the one
+    output."""
+    if mesh is None:
+        return fn(*args)
+    sizes = dict(mesh.shape)
+    use: dict = {}
+    for a, d in zip(args, dims):
+        for n, ax in zip(a.shape, d):
+            if ax is not None:
+                use[ax] = (use.get(ax, True) and sizes.get(ax, 1) > 1
+                           and n % sizes[ax] == 0)
+
+    def spec(d):
+        return jax.sharding.PartitionSpec(
+            *[ax if ax is not None and use.get(ax) else None for ax in d])
+
+    return jax.shard_map(fn, mesh=mesh, in_specs=tuple(map(spec, dims)),
+                         out_specs=spec(out_dims), check_vma=False)(*args)
+
+
+_ROWS = ("data", None)  # (M, K) activations: rows follow the batch
+
+
+def _row_shards(mesh) -> int:
+    """Row pieces a matmul's activations split into under ``mesh``: pad M
+    to ``bm`` times this, so every device's rows are whole blocks."""
+    return 1 if mesh is None else int(dict(mesh.shape).get("data", 1))
+
+
 def _env_flag(name: str) -> Optional[bool]:
     env = os.environ.get(name)
     if env is None:
@@ -225,6 +284,8 @@ class FallbackGuard:
 
     After the first trip the guard is latched: subsequent ``run`` calls go
     straight to the fallback path (no repeated failing-kernel attempts).
+    Every trip emits a ``RuntimeWarning`` carrying the exception, so a run
+    that silently fell back to XLA is visible in its output.
     ``faults``: optional ``serving.faults.FaultInjector`` consulted at
     ``site`` on every primary attempt — the harness provokes kernel
     raises/NaN-poisoning deterministically to prove this guard recovers.
@@ -265,6 +326,10 @@ class FallbackGuard:
             self.last_error = repr(e)
             for ax in self.axes:
                 trip_axis(ax)
+            warnings.warn(
+                f"FallbackGuard at site {self.site!r} tripped: "
+                f"{self.last_error}; dispatch axes {self.axes} latched to "
+                "the XLA path", RuntimeWarning, stacklevel=2)
             self.retries += 1
             return fn(*args, fallback=True)
 
@@ -383,6 +448,12 @@ def _pad1(x, m, value=0):
     return x
 
 
+def _tile(b: int, n: int, align: int) -> int:
+    """A block size the TPU can tile along a dim of size ``n``: a multiple
+    of ``align``, or one block spanning the whole (padded) dim."""
+    return b if b % align == 0 or b >= n else align
+
+
 def _act_scale_or_default(x, act_scale):
     """Calibrated scalar scale, or a dynamic max-abs fallback.
 
@@ -401,15 +472,18 @@ def _act_scale_or_default(x, act_scale):
 # ---------------------------------------------------------------------------
 
 
-@partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
-def _int8_core(x, wq, act_scale, scale, zero_point, bm, bn, bk, interpret):
+@partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret", "mesh"))
+def _int8_core(x, wq, act_scale, scale, zero_point, bm, bn, bk, interpret,
+               mesh=None):
     M, K = x.shape
     N = wq.shape[1]
-    xp = _pad2(x.astype(jnp.float32), bm, bk)
+    bn, bk = _tile(bn, N, 128), _tile(bk, K, 128)
+    xp = _pad2(x.astype(jnp.float32), bm * _row_shards(mesh), bk)
     wp = _pad2(wq, bk, bn)
-    y = int8_matmul(xp, wp, act_scale, _pad1(scale, bn),
-                    _pad1(zero_point, bn), bm=bm, bn=bn, bk=bk,
-                    interpret=interpret)
+    y = _on_mesh(mesh, partial(int8_matmul, bm=bm, bn=bn, bk=bk,
+                               interpret=interpret),
+                 (xp, wp, act_scale, _pad1(scale, bn), _pad1(zero_point, bn)),
+                 (_ROWS, (None, None), (), (None,), (None,)), _ROWS)
     return y[:M, :N]
 
 
@@ -418,24 +492,31 @@ def int8_matmul_op(x, wq, act_scale, scale, zero_point,
                    blocks: Optional[Tuple[int, int, int]] = None):
     """x (M,K) FLOAT activations; quantization is fused into the kernel."""
     interpret = _interpret_default() if interpret is None else interpret
+    mesh = _KERNEL_MESH.get()
     M, K = x.shape
     N = wq.shape[1]
     if blocks is None:
         blocks = autotune.blocks_for(
-            "int8_matmul", M, N, K, interpret=interpret,
+            "int8_matmul", M, N, K, interpret=interpret, operands=(x,),
             bench_fn=lambda b: _int8_core(x, wq, act_scale, scale, zero_point,
-                                          *b, interpret))
-    return _int8_core(x, wq, act_scale, scale, zero_point, *blocks, interpret)
+                                          *b, interpret, mesh))
+    return _int8_core(x, wq, act_scale, scale, zero_point, *blocks, interpret,
+                      mesh)
 
 
-@partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
-def _int4_core(x, packed, scale, zero_point, bm, bn, bk, interpret):
+@partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret", "mesh"))
+def _int4_core(x, packed, scale, zero_point, bm, bn, bk, interpret,
+               mesh=None):
     M, K = x.shape
     N = packed.shape[1] * 2
-    xp = _pad2(x, bm, bk)
+    # the packed block holds bn/2 lanes: bn in steps of 256
+    bn, bk = _tile(bn, N, 256), _tile(bk, K, 128)
+    xp = _pad2(x, bm * _row_shards(mesh), bk)
     pp = _pad2(packed, bk, bn // 2)
-    y = int4_matmul(xp, pp, _pad1(scale, bn), _pad1(zero_point, bn),
-                    bm=bm, bn=bn, bk=bk, interpret=interpret)
+    y = _on_mesh(mesh, partial(int4_matmul, bm=bm, bn=bn, bk=bk,
+                               interpret=interpret),
+                 (xp, pp, _pad1(scale, bn), _pad1(zero_point, bn)),
+                 (_ROWS, (None, None), (None,), (None,)), _ROWS)
     return y[:M, :N]
 
 
@@ -443,52 +524,60 @@ def int4_matmul_op(x, packed, scale, zero_point,
                    interpret: Optional[bool] = None,
                    blocks: Optional[Tuple[int, int, int]] = None):
     interpret = _interpret_default() if interpret is None else interpret
+    mesh = _KERNEL_MESH.get()
     M, K = x.shape
     N = packed.shape[1] * 2
     if blocks is None:
         blocks = autotune.blocks_for(
-            "int4_matmul", M, N, K, interpret=interpret,
+            "int4_matmul", M, N, K, interpret=interpret, operands=(x,),
             bench_fn=lambda b: _int4_core(x, packed, scale, zero_point, *b,
-                                          interpret))
-    return _int4_core(x, packed, scale, zero_point, *blocks, interpret)
+                                          interpret, mesh))
+    return _int4_core(x, packed, scale, zero_point, *blocks, interpret, mesh)
 
 
-@partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
-def _apot_core(x, codes, scale, bm, bn, bk, interpret):
+@partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret", "mesh"))
+def _apot_core(x, codes, scale, bm, bn, bk, interpret, mesh=None):
     M, K = x.shape
     N = codes.shape[1]
-    xp = _pad2(x, bm, bk)
+    xp = _pad2(x, bm * _row_shards(mesh), bk)
     # pad codes with the zero-flag byte so padded weights decode to 0
     cp = _pad2(codes, bk, bn, value=0x80)
-    y = apot_matmul(xp, cp, _pad1(scale, bn), bm=bm, bn=bn, bk=bk,
-                    interpret=interpret)
+    y = _on_mesh(mesh, partial(apot_matmul, bm=bm, bn=bn, bk=bk,
+                               interpret=interpret),
+                 (xp, cp, _pad1(scale, bn)),
+                 (_ROWS, (None, None), (None,)), _ROWS)
     return y[:M, :N]
 
 
 def apot_matmul_op(x, codes, scale, interpret: Optional[bool] = None,
                    blocks: Optional[Tuple[int, int, int]] = None):
     interpret = _interpret_default() if interpret is None else interpret
+    mesh = _KERNEL_MESH.get()
     M, K = x.shape
     N = codes.shape[1]
     if blocks is None:
         blocks = autotune.blocks_for(
-            "apot_matmul", M, N, K, interpret=interpret,
-            bench_fn=lambda b: _apot_core(x, codes, scale, *b, interpret))
-    return _apot_core(x, codes, scale, *blocks, interpret)
+            "apot_matmul", M, N, K, interpret=interpret, operands=(x,),
+            bench_fn=lambda b: _apot_core(x, codes, scale, *b, interpret,
+                                          mesh))
+    return _apot_core(x, codes, scale, *blocks, interpret, mesh)
 
 
-@partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
+@partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret", "mesh"))
 def _m2q_core(x, act_scale, payload, u_scale, u_zp, a_scale,
-              bm, bn, bk, interpret):
+              bm, bn, bk, interpret, mesh=None):
     M, K = x.shape
     N = payload.shape[1]
-    xp = _pad2(x.astype(jnp.float32), bm, bk)
+    bn, bk = _tile(bn, N, 128), _tile(bk, K, 128)
+    xp = _pad2(x.astype(jnp.float32), bm * _row_shards(mesh), bk)
     # K-pad rows of the payload multiply quantized-zero activations; N-pad
     # columns carry zero scales — both vanish, any pad byte is safe.
     pp = _pad2(payload, bk, bn)
-    y = m2q_matmul(xp, act_scale, pp, _pad1(u_scale, bn), _pad1(u_zp, bn),
-                   _pad1(a_scale, bn), bm=bm, bn=bn, bk=bk,
-                   interpret=interpret)
+    y = _on_mesh(mesh, partial(m2q_matmul, bm=bm, bn=bn, bk=bk,
+                               interpret=interpret),
+                 (xp, act_scale, pp, _pad1(u_scale, bn), _pad1(u_zp, bn),
+                  _pad1(a_scale, bn)),
+                 (_ROWS, (), (None, None), (None,), (None,), (None,)), _ROWS)
     return y[:M, :N]
 
 
@@ -502,21 +591,22 @@ def m2q_matmul_op(x, act_scale, payload, u_scale, u_zp, a_scale,
     both engine halves summed in the kernel epilogue, no concat/gather.
     """
     interpret = _interpret_default() if interpret is None else interpret
+    mesh = _KERNEL_MESH.get()
     M, K = x.shape
     N = payload.shape[1]
     if blocks is None:
         blocks = autotune.blocks_for(
-            "m2q_matmul", M, N, K, interpret=interpret,
+            "m2q_matmul", M, N, K, interpret=interpret, operands=(x,),
             bench_fn=lambda b: _m2q_core(x, act_scale, payload, u_scale,
-                                         u_zp, a_scale, *b, interpret))
+                                         u_zp, a_scale, *b, interpret, mesh))
     return _m2q_core(x, act_scale, payload, u_scale, u_zp, a_scale, *blocks,
-                     interpret)
+                     interpret, mesh)
 
 
 @partial(jax.jit, static_argnames=("kh", "kw", "stride", "bh", "bc",
-                                   "fuse_pad", "interpret"))
+                                   "interpret", "mesh"))
 def _dwconv_core(x, packed, scale, zero_point, kh, kw, stride, bh, bc,
-                 fuse_pad, interpret):
+                 interpret, mesh=None):
     C = x.shape[-1]
     pc = (-C) % bc
     if pc:
@@ -524,15 +614,21 @@ def _dwconv_core(x, packed, scale, zero_point, kh, kw, stride, bh, bc,
         packed = jnp.pad(packed, ((0, 0), (0, pc // 2)))
         scale = jnp.pad(scale, (0, pc))
         zero_point = jnp.pad(zero_point, (0, pc))
-    y = dwconv_w4(x, packed, scale, zero_point, kh=kh, kw=kw, stride=stride,
-                  bh=bh, bc=bc, fuse_pad=fuse_pad, interpret=interpret)
+    img = ("data", None, None, None)
+    y = _on_mesh(mesh, partial(dwconv_w4, kh=kh, kw=kw, stride=stride,
+                               bh=bh, bc=bc, interpret=interpret),
+                 (x, packed, scale, zero_point),
+                 (img, (None, None), (None,), (None,)), img)
     return y[..., :C]
 
 
 def _dwconv_bc(bn: int, C: int) -> int:
-    """Channel block: capped at C and even (nibble pairs)."""
+    """Channel block: all C channels, or a multiple of 128 lanes (the TPU
+    tiling rule for a block's last dimension)."""
     bc = min(bn, C)
-    return max(bc - (bc % 2), 2)
+    if bc < C and bc % 128:
+        bc = min(128, C)
+    return bc
 
 
 # Per-grid-block VMEM budget for the H-tiled dwconv kernel.  With H-tiling
@@ -545,13 +641,20 @@ _DWCONV_VMEM_BYTES = 8 * 1024 * 1024
 
 def _dwconv_tile_bytes(W: int, kh: int, kw: int, stride: int,
                        bh: int, bc: int) -> int:
-    """f32 VMEM bytes one (bh, bc) grid block touches at map width W."""
+    """f32 VMEM bytes one (bh, bc) grid block touches at map width W, with
+    the (8, 128) tile padding VMEM applies to a block's last two dims."""
     pw = same_padding(W, kw, stride)
-    wi = W + pw[0] + pw[1]
-    wo = -(-W // stride)
+    wi = _round_up(W + pw[0] + pw[1], 8)
+    wo = _round_up(-(-W // stride), 8)
+    lanes = _round_up(bc, 128)
     bh_in = (bh - 1) * stride + kh
     # input slab + output slab + packed nibbles + decoded f32 weights
-    return (bh_in * wi + bh * wo) * bc * 4 + kh * kw * bc // 2 + kh * kw * bc * 4
+    return ((bh_in * wi + bh * wo) * lanes * 4
+            + _round_up(kh * kw, 8) * (_round_up(bc // 2, 128) + lanes * 4))
+
+
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
 
 
 def dwconv_tile_plan(H: int, W: int, kh: int, kw: int, stride: int,
@@ -560,16 +663,16 @@ def dwconv_tile_plan(H: int, W: int, kh: int, kw: int, stride: int,
                      ) -> Optional[Tuple[int, int]]:
     """Fit an H-tile plan (bh output rows, bc channels) under the VMEM
     budget, shrinking the requested blocks (rows first — channel tiles keep
-    lane utilization) until one block fits.  Returns None only when even
-    the minimal (1, 2) tile exceeds the budget — i.e. the tiler genuinely
-    cannot block the map, not merely that the whole map is large."""
+    lane utilization; channels only down to one 128-lane tile) until one
+    block fits.  Returns None only when even the minimal (1, 128) tile
+    exceeds the budget — i.e. the tiler genuinely cannot block the map,
+    not merely that the whole map is large."""
     ho = -(-H // stride)
     bh = ho if bh is None else max(1, min(int(bh), ho))
-    bc = max(2, bc - (bc % 2))
     while bh > 1 and _dwconv_tile_bytes(W, kh, kw, stride, bh, bc) > budget:
         bh = max(1, bh // 2)
-    while bc > 2 and _dwconv_tile_bytes(W, kh, kw, stride, bh, bc) > budget:
-        bc = max(2, (bc // 2) - ((bc // 2) % 2))
+    while bc > 128 and _dwconv_tile_bytes(W, kh, kw, stride, bh, bc) > budget:
+        bc = max(128, bc // 256 * 128)
     if _dwconv_tile_bytes(W, kh, kw, stride, bh, bc) > budget:
         return None
     return bh, bc
@@ -577,29 +680,24 @@ def dwconv_tile_plan(H: int, W: int, kh: int, kw: int, stride: int,
 
 def dwconv_w4_op(x, packed, scale, zero_point, kh: int = 3, kw: int = 3,
                  stride: int = 1, interpret: Optional[bool] = None,
-                 blocks: Optional[Tuple[int, int, int]] = None,
-                 fuse_pad: Optional[bool] = None):
+                 blocks: Optional[Tuple[int, int, int]] = None):
     """x (B,H,W,C) float; packed (kh*kw, C/2) nibbles; SAME padding.
 
     The autotuner picks the (bh, bc) H-tile: candidate triples map bm -> bh
     (output rows per tile) and bn -> bc (channels per tile), each fitted
     under the VMEM budget by :func:`dwconv_tile_plan` before launch.
-    ``fuse_pad`` defaults to stride > 1 — the MBConv stage-entry
-    downsamplers pad inside the kernel instead of materializing a padded
-    copy of the full map.
     """
     interpret = _interpret_default() if interpret is None else interpret
+    mesh = _KERNEL_MESH.get()
     B, H, W, C = x.shape
     HO, WO = -(-H // stride), -(-W // stride)
     taps = kh * kw
-    if fuse_pad is None:
-        fuse_pad = stride > 1
 
     def _fit(b) -> Tuple[int, int]:
         plan = dwconv_tile_plan(H, W, kh, kw, stride,
                                 bh=min(int(b[0]), HO),
                                 bc=_dwconv_bc(int(b[1]), C))
-        return plan or (1, 2)
+        return plan or (1, _dwconv_bc(128, C))
 
     if blocks is None:
         # candidates are benched with the SAME fitted (bh, bc) that would
@@ -612,15 +710,15 @@ def dwconv_w4_op(x, packed, scale, zero_point, kh: int = 3, kw: int = 3,
                 cands.append(c)
         blocks = autotune.blocks_for(
             "dwconv_w4", B * HO * WO, C, taps,
-            interpret=interpret, candidates=cands,
+            interpret=interpret, candidates=cands, operands=(x,),
             meta={"B": B, "H": H, "W": W, "C": C, "kh": kh, "kw": kw,
                   "stride": stride},
             bench_fn=lambda b: _dwconv_core(x, packed, scale, zero_point,
                                             kh, kw, stride, *_fit(b),
-                                            fuse_pad, interpret))
+                                            interpret, mesh))
     bh, bc = _fit(blocks)
     return _dwconv_core(x, packed, scale, zero_point, kh, kw, stride, bh, bc,
-                        fuse_pad, interpret)
+                        interpret, mesh)
 
 
 # ---------------------------------------------------------------------------
@@ -637,8 +735,8 @@ def _pad_axis(x, axis: int, mult: int):
     return x
 
 
-@partial(jax.jit, static_argnames=("bn", "eps", "interpret"))
-def _relu_attn_core(q, k, v, bn, eps, interpret):
+@partial(jax.jit, static_argnames=("bn", "eps", "interpret", "mesh"))
+def _relu_attn_core(q, k, v, bn, eps, interpret, mesh=None):
     B, N, H, D = q.shape
     qf = q.astype(jnp.float32)
     kf = k.astype(jnp.float32)
@@ -649,13 +747,12 @@ def _relu_attn_core(q, k, v, bn, eps, interpret):
     sq = act_scale_from_stats(jnp.maximum(jnp.max(qf), 0.0))
     sk = act_scale_from_stats(jnp.maximum(jnp.max(kf), 0.0))
     sv = act_scale_from_stats(jnp.max(jnp.abs(vf)))
-    bd = autotune.heuristic_block(D)
-    qp = _pad_axis(_pad_axis(qf, 1, bn), 3, bd)
-    kp = _pad_axis(_pad_axis(kf, 1, bn), 3, bd)
-    vp = _pad_axis(_pad_axis(vf, 1, bn), 3, bd)
-    y = relu_attn(qp, kp, vp, sq, sk, sv, bn=bn, eps=eps,
-                  interpret=interpret)
-    return y[:, :N, :, :D]
+    heads = ("data", None, "model", None)
+    y = _on_mesh(mesh, partial(relu_attn, bn=bn, eps=eps, interpret=interpret),
+                 (_pad_axis(qf, 1, bn), _pad_axis(kf, 1, bn),
+                  _pad_axis(vf, 1, bn), sq, sk, sv),
+                 (heads, heads, heads, (), (), ()), heads)
+    return y[:, :N]
 
 
 def relu_attn_op(q, k, v, eps: float = 1e-6,
@@ -667,6 +764,7 @@ def relu_attn_op(q, k, v, eps: float = 1e-6,
     changes the unpadded outputs; padded q rows are sliced away.
     """
     interpret = _interpret_default() if interpret is None else interpret
+    mesh = _KERNEL_MESH.get()
     B, N, H, D = q.shape
     if blocks is None:
         # only the q-row block matters (k/v/kv stay whole per (b, h));
@@ -678,9 +776,11 @@ def relu_attn_op(q, k, v, eps: float = 1e-6,
                 cands.append(c)
         blocks = autotune.blocks_for(
             "relu_attn", N, D, B * H, interpret=interpret, candidates=cands,
+            operands=(q,),
             meta={"B": B, "N": N, "H": H, "D": D},
-            bench_fn=lambda b: _relu_attn_core(q, k, v, b[0], eps, interpret))
-    return _relu_attn_core(q, k, v, blocks[0], eps, interpret)
+            bench_fn=lambda b: _relu_attn_core(q, k, v, b[0], eps, interpret,
+                                               mesh))
+    return _relu_attn_core(q, k, v, blocks[0], eps, interpret, mesh)
 
 
 def decode_attn_int8_op(q, k_q, v_q, k_scale, v_scale, lengths,
@@ -691,6 +791,7 @@ def decode_attn_int8_op(q, k_q, v_q, k_scale, v_scale, lengths,
     quantization definitions): q (B,1,Hq,D) float, int8 cache rows + per-row
     scales, lengths (B,).  Runs per (batch, kv-head) in one VMEM pass."""
     interpret = _interpret_default() if interpret is None else interpret
+    mesh = _KERNEL_MESH.get()
     B, _, Hq, D = q.shape
     Hkv = k_q.shape[2]
     G = Hq // Hkv
@@ -701,10 +802,15 @@ def decode_attn_int8_op(q, k_q, v_q, k_scale, v_scale, lengths,
                               "window": window or 0})
     scale = scale if scale is not None else 1.0 / math.sqrt(D)
     qh = q.reshape(B, Hkv, G, D).astype(jnp.float32)
-    out = decode_attn_int8(qh, k_q, v_q, k_scale, v_scale,
-                           jnp.asarray(lengths, jnp.int32).reshape(B, 1),
-                           scale=float(scale), window=window,
-                           interpret=interpret)
+    cache = ("data", None, "model", None)
+    out = _on_mesh(
+        mesh, partial(decode_attn_int8, scale=float(scale), window=window,
+                      interpret=interpret),
+        (qh, k_q, v_q, k_scale, v_scale,
+         jnp.asarray(lengths, jnp.int32).reshape(B, 1)),
+        (("data", "model", None, None), cache, cache,
+         ("data", None, "model"), ("data", None, "model"), ("data", None)),
+        ("data", "model", None, None))
     return out.reshape(B, 1, Hq, D).astype(q.dtype)
 
 

@@ -49,14 +49,6 @@ from typing import Callable, List, Optional, Sequence, Tuple
 CI_CONFIGS: Tuple[str, ...] = ("efficientvit-b1-r224", "qwen1.5-0.5b")
 CI_RECIPES: Tuple[str, ...] = ("m2q-w8a8", "uniform8")
 
-DEFAULT_CACHE_DIR = "results/autotune"
-
-
-def committed_cache_path(backend: Optional[str] = None) -> str:
-    import jax
-    b = backend or jax.default_backend()
-    return os.path.join(DEFAULT_CACHE_DIR, f"{b}.json")
-
 
 def _bench_fn(req, interpret: bool) -> Optional[Callable]:
     """Rebuild a real launch of the request's shape from synthetic operands
@@ -256,7 +248,7 @@ def main(argv=None) -> int:
                     help="comma-joined quantization recipes")
     ap.add_argument("--cache", default=None,
                     help="cache file to warm/check (default "
-                         f"{DEFAULT_CACHE_DIR}/<backend>.json)")
+                         "<checkout>/results/autotune/<backend>.json)")
     ap.add_argument("--smoke", action="store_true",
                     help="CI gate: assert the committed cache covers the "
                          "pinned CI shape set (no warming; missing shapes "
@@ -272,7 +264,9 @@ def main(argv=None) -> int:
                          "rows are slow); <=0 means no limit")
     args = ap.parse_args(argv)
 
-    cache_path = args.cache or committed_cache_path()
+    from ..kernels import autotune
+
+    cache_path = args.cache or autotune.committed_cache_path()
     # point trace-time lookups at the same file we warm/check, so the walk
     # exercises exactly the committed serving posture
     os.environ["REPRO_AUTOTUNE_CACHE"] = cache_path

@@ -446,6 +446,8 @@ def main():
     ap.add_argument("--process-id", type=int, default=0)
     args = ap.parse_args()
 
+    from ..checkout import enable_compile_cache
+    enable_compile_cache()
     if args.coordinator is not None:
         sys.exit(multihost_dryrun(args))
     if args.recovery_smoke:

@@ -333,6 +333,22 @@ def op_histogram(text: str, weighted: bool = True,
     return hist
 
 
+_KERNEL_CALL_RE = re.compile(
+    r"^\s*(?:ROOT\s+)?%([A-Za-z_]\w*?)(?:\.\d+)*\s*=.*"
+    r'custom_call_target="tpu_custom_call"', re.M)
+
+
+def kernel_counts(text: str) -> Dict[str, int]:
+    """{kernel name: number of ``tpu_custom_call`` instructions} in compiled
+    TPU HLO text.  Each Pallas kernel's ``pallas_call(name=...)`` becomes
+    the instruction name (``%m2q_matmul.3 = ... custom-call(...)``), so the
+    counts say which kernels a compiled program really launches."""
+    counts: Dict[str, int] = {}
+    for m in _KERNEL_CALL_RE.finditer(text):
+        counts[m.group(1)] = counts.get(m.group(1), 0) + 1
+    return counts
+
+
 def analyze(text: str) -> dict:
     comps = parse_computations(text)
     mult = computation_multipliers(comps)

@@ -19,6 +19,7 @@ import time
 import jax
 import numpy as np
 
+from ..checkout import enable_compile_cache
 from ..configs.registry import ARCHS, REDUCED
 from ..models import get_model
 from ..recipe import QuantizedModel, as_recipe, quantize
@@ -52,7 +53,8 @@ def parse_mesh(spec: str):
             f"{n_dev} exist (set XLA_FLAGS="
             f"--xla_force_host_platform_device_count={n_data * n_model} "
             "before launch for a virtual mesh)")
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+    return jax.make_mesh((n_data, n_model), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 def main():
@@ -72,6 +74,7 @@ def main():
     ap.add_argument("--no-quant", action="store_true")
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = (REDUCED if args.reduced else ARCHS)[args.arch]
     model = get_model(cfg)
     params = model.init(cfg, jax.random.PRNGKey(0))

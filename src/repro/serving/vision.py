@@ -140,8 +140,13 @@ class VisionEngine:
                                              mesh))
 
     def _dispatch_scope(self):
-        return (_kops.dispatch(self.dispatch) if self.dispatch is not None
-                else contextlib.nullcontext())
+        """The kernel-dispatch pin and the engine's mesh, for its traces
+        (kernels launch per device under a mesh: ``ops.kernel_mesh``)."""
+        scope = contextlib.ExitStack()
+        if self.dispatch is not None:
+            scope.enter_context(_kops.dispatch(self.dispatch))
+        scope.enter_context(_kops.kernel_mesh(self.mesh))
+        return scope
 
     def _fwd_impl(self, params, images, fallback=False):
         # fallback=True (static) pins the retry trace to the XLA path —

@@ -82,6 +82,42 @@ def test_foreign_backend_entries_never_serve(tmp_path):
     assert autotune.AutotuneCache(path).load().get(foreign) == (8, 8, 8)
 
 
+def test_default_cache_path_is_the_committed_file_in_the_checkout(
+        monkeypatch):
+    """With no REPRO_AUTOTUNE_CACHE the lookup reads the per-backend file
+    the sweep writes and the repo commits — inside the checkout, never
+    under $HOME — so a compiled program depends only on committed files."""
+    from repro.checkout import ROOT
+    monkeypatch.delenv("REPRO_AUTOTUNE_CACHE", raising=False)
+    path = autotune.default_cache_path()
+    assert path == str(ROOT / "results" / "autotune"
+                       / f"{jax.default_backend()}.json")
+    assert path == autotune.committed_cache_path()
+    assert autotune.default_cache_path("tpu").endswith(
+        "results/autotune/tpu.json")
+    assert (ROOT / "src" / "repro" / "checkout.py").is_file()
+    monkeypatch.setenv("REPRO_AUTOTUNE_CACHE", "/elsewhere/cache.json")
+    assert autotune.default_cache_path() == "/elsewhere/cache.json"
+
+
+def test_compile_cache_defaults_into_the_checkout(monkeypatch):
+    """``JAX_COMPILATION_CACHE_DIR`` set: nothing is set in code.  Unset:
+    the persistent compile cache is ``<checkout>/.jax_cache``."""
+    from repro.checkout import ROOT, enable_compile_cache
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        jax.config.update("jax_compilation_cache_dir", None)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/jax")
+        assert enable_compile_cache() == "/elsewhere/jax"
+        assert jax.config.jax_compilation_cache_dir is None
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert enable_compile_cache() == str(ROOT / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == str(ROOT /
+                                                           ".jax_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+
+
 # ---------------------------------------------------------------------------
 # zero tuning probes at trace time against a warmed cache
 # ---------------------------------------------------------------------------

@@ -323,7 +323,7 @@ def test_dwconv_high_res_kernel_matches_xla_reference(res, stride,
     """ISSUE 9 acceptance: R256/R384 depthwise maps execute on the Pallas
     w4 kernel (dispatch-on nn.dwconv2d routes there, the H-tiled grid) and
     match the dequantized-weight XLA conv — triangulated over stride-1 and
-    the fused-pad stride-2 downsampler path."""
+    the stride-2 downsampler path."""
     rng = _rng(res + stride)
     C = 4
     w4 = rng.normal(0, 0.2, (3, 3, 1, C)).astype(np.float32)

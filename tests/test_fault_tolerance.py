@@ -298,6 +298,30 @@ def test_fallback_guard_retries_on_xla_with_matching_outputs():
     assert not _kops.axis_tripped("attn")
 
 
+def test_fallback_guard_trip_emits_runtime_warning():
+    """A trip is never silent: the guard warns with the kernel's exception
+    (a chip run that fell back to XLA shows it in its output)."""
+    _kops.reset_trip_latch()
+    try:
+        def step(x, fallback=False):
+            if not fallback:
+                raise ValueError("Mosaic refused the block shape")
+            return x
+
+        g = _kops.FallbackGuard(check_finite=False, site="vision.kernel",
+                                axes=("conv",))
+        with pytest.warns(RuntimeWarning,
+                          match=r"(?s)'vision.kernel' tripped.*Mosaic "
+                                r"refused the block shape.*conv"):
+            g.run(step, np.ones(2))
+        # latched afterwards: the fallback runs without a new warning
+        with warnings.catch_warnings():
+            warnings.simplefilter("error")
+            g.run(step, np.ones(2))
+    finally:
+        _kops.reset_trip_latch()
+
+
 def test_fallback_guard_nonfinite_output_trips_finite_check():
     _kops.reset_trip_latch()
     try:

@@ -362,7 +362,8 @@ def test_autotune_never_benches_inside_a_trace(tmp_path):
     def traced(x):
         blocks = autotune.blocks_for("fake_traced", 64, 64, 64,
                                      interpret=False, bench_fn=bench,
-                                     cache_path=path, force_tune=True)
+                                     cache_path=path, force_tune=True,
+                                     operands=(x,))
         assert blocks == autotune.heuristic_blocks(64, 64, 64)
         return x
 

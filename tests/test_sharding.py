@@ -138,7 +138,8 @@ _SMALL_DRYRUN = textwrap.dedent("""
     from repro.optim.adamw import AdamW
     from repro.train.step import make_train_step, make_serve_step
 
-    mesh = jax.make_mesh((4, 4), ("data", "model"))
+    mesh = jax.make_mesh((4, 4), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     cfg = REDUCED["qwen3-14b"].replace(dtype="bfloat16", act_sharding="data",
                                        attn_bf16_mm=True, causal_skip=True)
     model = get_model(cfg)
@@ -199,7 +200,8 @@ _SERVE_SHARDED_DRYRUN = textwrap.dedent("""
     from repro.models import get_model
     from repro.recipe import quantize
 
-    mesh = jax.make_mesh((4, 4), ("data", "model"))
+    mesh = jax.make_mesh((4, 4), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
     def assert_on_spec(tree, specs, what):
         leaves = jax.tree_util.tree_leaves(tree)
@@ -279,3 +281,65 @@ def test_sharded_serving_quantized_model_both_engines():
     rec = json.loads(out.stdout.strip().splitlines()[-1])
     assert rec["devices"] == 16
     assert rec["token_match"] and rec["vision_close"] and rec["handle_rows"]
+
+
+_KERNEL_MESH_DRYRUN = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import json
+    import numpy as np
+    import jax, jax.numpy as jnp
+    from repro.core.packing import pack_int4
+    from repro.kernels import ops
+    from repro.launch.serve import parse_mesh
+
+    rng = np.random.default_rng(0)
+    f = lambda *s: jnp.asarray(rng.normal(size=s), jnp.float32)
+    i8 = lambda *s: jnp.asarray(rng.integers(-127, 128, s), jnp.int8)
+    u4 = lambda *s: pack_int4(jnp.asarray(rng.integers(0, 16, s), jnp.uint8))
+    s, zp = jnp.asarray(rng.uniform(.01, .02, 128), jnp.float32), f(128)
+    # M = 196 rows: padded to one 128-row block per device, not 2 blocks
+    # split 4 ways (which would leave each device a partial block)
+    cases = {
+        "int8": (lambda x, w: ops.int8_matmul_op(
+            x, w, jnp.float32(.05), s, zp, interpret=True),
+            (f(196, 256), i8(256, 128))),
+        "int4": (lambda x, w: ops.int4_matmul_op(x, w, s, zp,
+                                                 interpret=True),
+                 (f(196, 256), u4(256, 128))),
+        "dwconv": (lambda x, w: ops.dwconv_w4_op(x, w, s[:8], zp[:8],
+                                                 stride=2, interpret=True),
+                   (f(4, 10, 10, 8), u4(9, 8))),
+        "relu_attn": (lambda q, k, v: ops.relu_attn_op(q, k, v,
+                                                       interpret=True),
+                      (f(4, 20, 4, 8), f(4, 20, 4, 8), f(4, 20, 4, 8))),
+        "decode": (lambda q, k, v, ks, vs: ops.decode_attn_int8_op(
+            q, k, v, ks, vs, jnp.asarray([3, 16, 1, 9]), interpret=True),
+            (f(4, 1, 4, 8), i8(4, 16, 4, 8), i8(4, 16, 4, 8),
+             jnp.abs(f(4, 16, 4)), jnp.abs(f(4, 16, 4)))),
+    }
+    err = {}
+    for name, (fn, args) in cases.items():
+        ref = fn(*args)
+        for spec in ("4x1", "2x2", "1x4"):
+            with ops.kernel_mesh(parse_mesh(spec)):
+                # a fresh function per mesh: jit's trace cache does not
+                # see the kernel_mesh scope
+                got = jax.jit(lambda *a: fn(*a))(*args)
+            err[f"{name}@{spec}"] = float(jnp.max(jnp.abs(got - ref)))
+    print(json.dumps(err))
+""")
+
+
+def test_kernel_launches_under_a_mesh_match_one_device():
+    """Under ``ops.kernel_mesh`` every kernel launch runs per device in a
+    ``shard_map`` (Mosaic kernels cannot be SPMD-partitioned): batch rows
+    split over ``data``, heads over ``model``.  On 4 virtual devices each
+    kernel reproduces its unsharded result exactly, for data-, mixed- and
+    model-parallel meshes."""
+    out = subprocess.run([sys.executable, "-c", _KERNEL_MESH_DRYRUN],
+                         capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-3000:]
+    err = json.loads(out.stdout.strip().splitlines()[-1])
+    assert len(err) == 15
+    assert all(e == 0.0 for e in err.values()), err
