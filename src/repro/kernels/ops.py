@@ -56,6 +56,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from .. import tracing
 from ..core.qtensor import QAPoT, QExpertM2Q, QM2Q, QUniform
 from ..core.quant import act_scale_from_stats
 from . import autotune
@@ -289,15 +290,21 @@ class FallbackGuard:
     ``faults``: optional ``serving.faults.FaultInjector`` consulted at
     ``site`` on every primary attempt — the harness provokes kernel
     raises/NaN-poisoning deterministically to prove this guard recovers.
+    ``span_prefix``: the guard's ``repro.tracing`` spans are
+    ``<span_prefix>.launch`` (each call of ``fn``) and
+    ``<span_prefix>.sync`` (the finite check, which waits on the device).
     """
 
     def __init__(self, check_finite: bool = True, faults=None,
                  site: str = "kernel",
-                 axes: Tuple[str, ...] = _TRIP_AXES):
+                 axes: Tuple[str, ...] = _TRIP_AXES,
+                 span_prefix: str = "kernel"):
         self.check_finite = check_finite
         self.faults = faults
         self.site = site
         self.axes = axes
+        self._launch = span_prefix + ".launch"
+        self._sync = span_prefix + ".sync"
         self.tripped = False
         self.trips = 0
         self.retries = 0
@@ -306,19 +313,24 @@ class FallbackGuard:
     def run(self, fn, *args):
         if self.tripped:
             self.retries += 1
-            return fn(*args, fallback=True)
+            with tracing.span(self._launch):
+                return fn(*args, fallback=True)
         act = self.faults.on_call(self.site) if self.faults is not None \
             else None
         try:
             if act is not None:
                 act.fire()
-            out = fn(*args, fallback=False)
+            with tracing.span(self._launch):
+                out = fn(*args, fallback=False)
             if act is not None and act.poison:
                 out = _poison_tree(out)
-            if self.check_finite and _tree_nonfinite(out):
-                raise NumericalError(
-                    f"non-finite output from kernel-dispatched step "
-                    f"(site {self.site!r}); retrying on the XLA path")
+            if self.check_finite:
+                with tracing.span(self._sync):
+                    bad = _tree_nonfinite(out)
+                if bad:
+                    raise NumericalError(
+                        f"non-finite output from kernel-dispatched step "
+                        f"(site {self.site!r}); retrying on the XLA path")
             return out
         except Exception as e:  # noqa: BLE001 — any failure degrades
             self.trips += 1
@@ -331,7 +343,8 @@ class FallbackGuard:
                 f"{self.last_error}; dispatch axes {self.axes} latched to "
                 "the XLA path", RuntimeWarning, stacklevel=2)
             self.retries += 1
-            return fn(*args, fallback=True)
+            with tracing.span(self._launch):
+                return fn(*args, fallback=True)
 
     def stats(self) -> dict:
         return {"tripped": self.tripped, "trips": self.trips,

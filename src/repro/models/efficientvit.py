@@ -172,18 +172,26 @@ def _msa(p, x, dim_per_head=16):
 
 def forward(cfg: ArchConfig, params, images, unroll: bool = False,
             remat: bool = False):
-    """images: (B, res, res, 3) -> logits (B, n_classes)."""
+    """images: (B, res, res, 3) -> logits (B, n_classes).
+
+    Each part runs under a ``jax.named_scope`` (``stem``, ``stage0``...,
+    ``head``), which the compiled program keeps in every instruction's
+    ``op_name`` metadata: a profile's device ops map back to their stage.
+    """
     dtype = jnp.dtype(cfg.dtype)
-    x = images.astype(dtype)
-    x = nn.conv2d(x, params["stem"]["w"], stride=2)
-    x = nn.silu(_cln(x, params["stem"]["ln"]))
+    with jax.named_scope("stem"):
+        x = images.astype(dtype)
+        x = nn.conv2d(x, params["stem"]["w"], stride=2)
+        x = nn.silu(_cln(x, params["stem"]["ln"]))
     for si, blocks in enumerate(params["stages"]):
-        for bi, blk in enumerate(blocks):
-            stride = 2 if (bi == 0 and si > 0) else 1
-            x = _mbconv(blk["mb"], x, stride=stride)
-            if "msa" in blk:
-                x = _msa(blk["msa"], x, cfg.dim_per_head)
-    x = nn.conv2d(x, params["head"]["w_in"])
-    x = nn.silu(_cln(x, params["head"]["ln"]))
-    x = jnp.mean(x, axis=(1, 2))  # global pool
-    return nn.dense(x, params["head"]["w"])
+        with jax.named_scope(f"stage{si}"):
+            for bi, blk in enumerate(blocks):
+                stride = 2 if (bi == 0 and si > 0) else 1
+                x = _mbconv(blk["mb"], x, stride=stride)
+                if "msa" in blk:
+                    x = _msa(blk["msa"], x, cfg.dim_per_head)
+    with jax.named_scope("head"):
+        x = nn.conv2d(x, params["head"]["w_in"])
+        x = nn.silu(_cln(x, params["head"]["ln"]))
+        x = jnp.mean(x, axis=(1, 2))  # global pool
+        return nn.dense(x, params["head"]["w"])

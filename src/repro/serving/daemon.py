@@ -45,6 +45,7 @@ import threading
 import time
 from typing import Dict, Optional
 
+from .. import tracing
 from .batching import ServeStats
 from .errors import QueueFullError
 from .scheduler import DONE, FAILED, Handle
@@ -309,7 +310,8 @@ class ServingDaemon:
         sched = self.engine.scheduler
         while True:
             self.step_started = time.monotonic()
-            busy = self._tick() > 0
+            with tracing.span("daemon.tick"):
+                busy = self._tick() > 0
             self.step_started = None
             self.heartbeat = time.monotonic()
             with self._wake:
@@ -317,7 +319,8 @@ class ServingDaemon:
                     if not self._drain or self._idle():
                         return
                     if not busy:  # e.g. coalescing deadline not yet due
-                        self._wake.wait(timeout=0.005)
+                        with tracing.span("daemon.sleep"):
+                            self._wake.wait(timeout=0.005)
                     continue  # draining: keep serving
                 if busy:
                     continue  # hot: decode slots live or queue due
@@ -331,7 +334,8 @@ class ServingDaemon:
                 timeout = (None if nd is None
                            else max(0.0, nd - sched.clock()))
                 if timeout is None or timeout > 0:
-                    self._wake.wait(timeout=timeout)
+                    with tracing.span("daemon.sleep"):
+                        self._wake.wait(timeout=timeout)
 
     # -- reporting -----------------------------------------------------------
     def stats_summary(self) -> Dict[str, object]:

@@ -56,6 +56,7 @@ import threading
 import time
 from typing import Callable, Iterator, List, Optional, Sequence
 
+from .. import tracing
 from .batching import ServeStats
 from .errors import CancelledError, QueueFullError, RequestTimedOut
 
@@ -508,7 +509,9 @@ class Scheduler:
         if deadline_ms is not None and deadline_ms <= 0:
             raise ValueError(f"deadline_ms must be > 0, got {deadline_ms}")
         shed: List[Handle] = []
-        with self._lock:
+        # span sched.enqueue: the wait for the queue lock and the section
+        # under it, not the shed transitions or the inline poll below
+        with tracing.span("sched.enqueue"), self._lock:
             now = self.now()
             self.expire(now)
             cap = self.overload.max_queue

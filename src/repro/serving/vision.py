@@ -46,6 +46,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import tracing
 from ..kernels import ops as _kops
 from ..models import get_model
 from ..models.config import ArchConfig
@@ -106,7 +107,8 @@ class VisionEngine:
         # finite check here is cheap (the vision path syncs per batch
         # anyway) so a NaN-producing kernel also degrades to XLA
         self.fallback_guard = _kops.FallbackGuard(
-            check_finite=True, faults=self.faults, site="vision.kernel")
+            check_finite=True, faults=self.faults, site="vision.kernel",
+            span_prefix="vision")
         # real-clock time poll() last entered (supervision liveness signal,
         # independent of any injected virtual scheduler clock)
         self.heartbeat: Optional[float] = None
@@ -168,16 +170,21 @@ class VisionEngine:
         n = images.shape[0]
         pad = bucket - n
         if pad:
-            images = np.concatenate(
-                [images, np.zeros((pad,) + images.shape[1:], np.float32)])
-        x = jnp.asarray(images)
-        if self._batch_spec is not None:
-            x = jax.device_put(x, self._batch_spec)
+            with tracing.span("vision.assemble"):
+                images = np.concatenate(
+                    [images, np.zeros((pad,) + images.shape[1:],
+                                      np.float32)])
+        with tracing.span("vision.put"):
+            x = jnp.asarray(images)
+            if self._batch_spec is not None:
+                x = jax.device_put(x, self._batch_spec)
         with self._dispatch_scope():
+            # spans vision.launch and vision.sync
             logits = self.fallback_guard.run(self._fwd, self.params, x)
         self.stats.record_batch(items=n, padded=pad, capacity=self.B,
                                 bucket=bucket)
-        return np.asarray(logits)[:n]
+        with tracing.span("vision.fetch"):
+            return np.asarray(logits)[:n]
 
     def _execute(self, handles: List[Handle], reason: str) -> None:
         """Scheduler executor: one flushed batch -> per-handle logits.
@@ -188,26 +195,36 @@ class VisionEngine:
         injected ``vision``-site fault, an OOM, a raise surviving the
         guard's XLA retry) is contained by the scheduler core: it fails
         this batch's handles and the serving loop keeps running.
+
+        Spans: ``vision.batch`` over all of it; inside, in order,
+        ``vision.assemble`` (stack, dtype, padding), ``vision.put``,
+        ``vision.launch``, ``vision.sync``, ``vision.fetch`` and
+        ``vision.deliver`` (row checks, results, done-callbacks).
         """
-        act = (self.faults.on_call("vision")
-               if self.faults is not None else None)
-        if act is not None:
-            act.fire()  # raises/delays before any work runs
-        imgs = np.stack([h.payload for h in handles]).astype(np.float32)
-        out = self._run_batch(imgs, self.bucket(len(handles)))
-        if act is not None and act.poison:
-            # simulated silent corruption of the batch's outputs: poison
-            # ONE row — that request fails alone, batchmates deliver
-            out = out.copy()
-            out[0] = np.nan
-        for i, (h, row) in enumerate(zip(handles, out)):
-            if self.check_numerics and not np.all(np.isfinite(row)):
-                h.set_exception(NumericalError(
-                    f"request {h.uid}: non-finite logits from the vision "
-                    f"forward (row {i} of the executed batch); its result "
-                    "was not delivered"))
-            else:
-                h.set_result(row)
+        with tracing.span("vision.batch"):
+            act = (self.faults.on_call("vision")
+                   if self.faults is not None else None)
+            if act is not None:
+                act.fire()  # raises/delays before any work runs
+            with tracing.span("vision.assemble"):
+                imgs = np.stack([h.payload for h in handles]) \
+                    .astype(np.float32)
+            out = self._run_batch(imgs, self.bucket(len(handles)))
+            if act is not None and act.poison:
+                # simulated silent corruption of the batch's outputs:
+                # poison ONE row — that request fails alone, batchmates
+                # deliver
+                out = out.copy()
+                out[0] = np.nan
+            with tracing.span("vision.deliver"):
+                for i, (h, row) in enumerate(zip(handles, out)):
+                    if self.check_numerics and not np.all(np.isfinite(row)):
+                        h.set_exception(NumericalError(
+                            f"request {h.uid}: non-finite logits from the "
+                            f"vision forward (row {i} of the executed "
+                            "batch); its result was not delivered"))
+                    else:
+                        h.set_result(row)
 
     # -- request API ---------------------------------------------------------
     def submit(self, image: np.ndarray,
@@ -226,20 +243,22 @@ class VisionEngine:
         row's).  Raises ``QueueFullError`` when a bounded queue rejects
         the submit (see ``OverloadPolicy``).
         """
-        img = np.asarray(image)
-        if img.shape != (self.cfg.img_res, self.cfg.img_res, 3):
-            raise ValueError(
-                f"expected ({self.cfg.img_res}, {self.cfg.img_res}, 3), "
-                f"got {img.shape}")
-        if not np.issubdtype(img.dtype, np.number) \
-                or np.issubdtype(img.dtype, np.complexfloating):
-            raise ValueError(
-                f"image dtype must be real-numeric pixels, got {img.dtype}")
-        if np.issubdtype(img.dtype, np.floating) \
-                and not np.all(np.isfinite(img)):
-            raise ValueError(
-                "image holds NaN/Inf pixels; refusing to enqueue a payload "
-                "that would poison its whole executed batch")
+        with tracing.span("vision.validate"):
+            img = np.asarray(image)
+            if img.shape != (self.cfg.img_res, self.cfg.img_res, 3):
+                raise ValueError(
+                    f"expected ({self.cfg.img_res}, {self.cfg.img_res}, 3), "
+                    f"got {img.shape}")
+            if not np.issubdtype(img.dtype, np.number) \
+                    or np.issubdtype(img.dtype, np.complexfloating):
+                raise ValueError(
+                    "image dtype must be real-numeric pixels, got "
+                    f"{img.dtype}")
+            if np.issubdtype(img.dtype, np.floating) \
+                    and not np.all(np.isfinite(img)):
+                raise ValueError(
+                    "image holds NaN/Inf pixels; refusing to enqueue a "
+                    "payload that would poison its whole executed batch")
         return self.scheduler.submit(img, deadline_ms=deadline_ms)
 
     def poll(self) -> int:
