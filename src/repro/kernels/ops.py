@@ -251,14 +251,13 @@ def reset_trip_latch() -> None:
         _TRIP_LATCH[ax] = 0
 
 
-def _tree_nonfinite(out) -> bool:
-    """True if any inexact-dtype array leaf holds a NaN/Inf (syncs)."""
-    for leaf in jax.tree_util.tree_leaves(out):
-        if (isinstance(leaf, jax.Array)
-                and jnp.issubdtype(leaf.dtype, jnp.inexact)
-                and not bool(jnp.all(jnp.isfinite(leaf)))):
-            return True
-    return False
+def _finite_flags(out) -> list:
+    """One device scalar per inexact-dtype array leaf: all of it finite.
+    Dispatched behind ``out``, not waited on; ``bool`` of each syncs."""
+    return [jnp.all(jnp.isfinite(leaf))
+            for leaf in jax.tree_util.tree_leaves(out)
+            if isinstance(leaf, jax.Array)
+            and jnp.issubdtype(leaf.dtype, jnp.inexact)]
 
 
 def _poison_tree(out):
@@ -287,6 +286,8 @@ class FallbackGuard:
     straight to the fallback path (no repeated failing-kernel attempts).
     Every trip emits a ``RuntimeWarning`` carrying the exception, so a run
     that silently fell back to XLA is visible in its output.
+    ``run`` is :meth:`launch` then :meth:`finish`; a caller that waits on
+    the device on another thread calls the two itself.
     ``faults``: optional ``serving.faults.FaultInjector`` consulted at
     ``site`` on every primary attempt — the harness provokes kernel
     raises/NaN-poisoning deterministically to prove this guard recovers.
@@ -311,10 +312,18 @@ class FallbackGuard:
         self.last_error: Optional[str] = None
 
     def run(self, fn, *args):
+        return self.finish(*self.launch(fn, *args), fn, *args)
+
+    def launch(self, fn, *args):
+        """The first half of :meth:`run`: the fault, the call of ``fn``,
+        the poison, and (with ``check_finite``) the finite check's device
+        work, queued right behind ``fn``'s without waiting on it.  Returns
+        ``(out, flags)``; hand both to :meth:`finish`, which waits on
+        ``flags`` where there are any (None: nothing left to check)."""
         if self.tripped:
             self.retries += 1
             with tracing.span(self._launch):
-                return fn(*args, fallback=True)
+                return fn(*args, fallback=True), None
         act = self.faults.on_call(self.site) if self.faults is not None \
             else None
         try:
@@ -324,27 +333,40 @@ class FallbackGuard:
                 out = fn(*args, fallback=False)
             if act is not None and act.poison:
                 out = _poison_tree(out)
-            if self.check_finite:
-                with tracing.span(self._sync):
-                    bad = _tree_nonfinite(out)
-                if bad:
-                    raise NumericalError(
-                        f"non-finite output from kernel-dispatched step "
-                        f"(site {self.site!r}); retrying on the XLA path")
+            return out, (_finite_flags(out) if self.check_finite else None)
+        except Exception as e:  # noqa: BLE001 — any failure degrades
+            return self._retry(e, fn, args), None
+
+    def finish(self, out, flags, fn, *args):
+        """The second half of :meth:`run`: wait on the finite ``flags``
+        that :meth:`launch` queued, if any; a raise or a non-finite
+        output trips the guard and re-runs ``fn`` on the XLA path."""
+        if flags is None:
+            return out
+        try:
+            with tracing.span(self._sync):
+                bad = not all(bool(f) for f in flags)
+            if bad:
+                raise NumericalError(
+                    f"non-finite output from kernel-dispatched step "
+                    f"(site {self.site!r}); retrying on the XLA path")
             return out
         except Exception as e:  # noqa: BLE001 — any failure degrades
-            self.trips += 1
-            self.tripped = True
-            self.last_error = repr(e)
-            for ax in self.axes:
-                trip_axis(ax)
-            warnings.warn(
-                f"FallbackGuard at site {self.site!r} tripped: "
-                f"{self.last_error}; dispatch axes {self.axes} latched to "
-                "the XLA path", RuntimeWarning, stacklevel=2)
-            self.retries += 1
-            with tracing.span(self._launch):
-                return fn(*args, fallback=True)
+            return self._retry(e, fn, args)
+
+    def _retry(self, e: Exception, fn, args):
+        self.trips += 1
+        self.tripped = True
+        self.last_error = repr(e)
+        for ax in self.axes:
+            trip_axis(ax)
+        warnings.warn(
+            f"FallbackGuard at site {self.site!r} tripped: "
+            f"{self.last_error}; dispatch axes {self.axes} latched to "
+            "the XLA path", RuntimeWarning, stacklevel=3)
+        self.retries += 1
+        with tracing.span(self._launch):
+            return fn(*args, fallback=True)
 
     def stats(self) -> dict:
         return {"tripped": self.tripped, "trips": self.trips,

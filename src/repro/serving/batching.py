@@ -60,6 +60,8 @@ class ServeStats:
       ``submitted == resolved`` reconciles once traffic drains.
       ``rejected`` counts submits the OverloadPolicy refused — those
       never created a handle and are NOT part of ``submitted``.
+    * ``overlapped_batches`` — batches launched while an earlier batch was
+      still in flight (the vision engine under a serving daemon).
 
     Thread-safety: the ``record_*`` mutators serialize on an internal
     lock (not a dataclass field — ``reset()``/``fields()`` never touch
@@ -81,6 +83,7 @@ class ServeStats:
     timed_out: int = 0        # per-request deadline expiry -> TIMED_OUT
     shed: int = 0             # load shedding (FAILED w/ QueueFullError)
     rejected: int = 0         # submits refused up front (no handle made)
+    overlapped_batches: int = 0
     queue_ms: List[float] = dataclasses.field(default_factory=list)
     flush_reasons: Dict[str, int] = dataclasses.field(default_factory=dict)
     buckets_used: Set[int] = dataclasses.field(default_factory=set)
@@ -104,6 +107,10 @@ class ServeStats:
             self.capacity_items += capacity if capacity else items + padded
             if bucket:
                 self.buckets_used.add(bucket)
+
+    def record_overlap(self) -> None:
+        with self._lock:
+            self.overlapped_batches += 1
 
     def record_flush(self, reason: str) -> None:
         with self._lock:
@@ -179,6 +186,7 @@ class ServeStats:
             "timed_out": self.timed_out,
             "shed": self.shed,
             "rejected": self.rejected,
+            "overlapped_batches": self.overlapped_batches,
             "p50_ms": round(self.p50_ms, 4),
             "p99_ms": round(self.p99_ms, 4),
             "batch_occupancy": round(self.batch_occupancy, 4),

@@ -32,6 +32,14 @@ the engine may evict (restart-from-prefix) for higher tiers.  Per-class
 ``daemon.class_stats["interactive"].p99_ms < ...["batch"].p99_ms`` is a
 measurable SLO, not a hope.
 
+A vision engine's batches complete on a second thread the daemon owns,
+``repro-complete`` (:class:`~repro.serving.vision.Completions`, started
+by :meth:`start`, joined by :meth:`shutdown`): a submit that fills a
+batch, or a flush of the serve loop, returns once the batch is launched,
+so the host stacks and copies the next batch while the device runs this
+one, and results arrive from that thread.  Direct calls on the engine
+still return with their batch delivered.
+
 Shutdown: ``shutdown(drain=True)`` stops intake and serves everything
 outstanding to a terminal state; ``drain=False`` (or a drain that hits
 ``timeout``) cancels what remains instead — either way every submitted
@@ -84,9 +92,8 @@ class ServingDaemon:
             classes, max_batch=sched.policy.max_batch)
         self.class_stats: Dict[str, ServeStats] = {
             name: ServeStats() for name in self.classes}
-        # RLock: a vision submit executes a due batch INLINE while the
-        # submitter holds _wake, and the batchmates' done-callbacks
-        # re-enter _wake on that same thread
+        # RLock: a submit that sheds older requests runs their
+        # done-callbacks, which re-enter _wake, on the submitting thread
         self._wake = threading.Condition(threading.RLock())
         self._state = _NEW
         self._drain = True
@@ -106,6 +113,13 @@ class ServingDaemon:
         # the set.  Guarded by _wake's lock.
         self._outstanding: Dict[int, str] = {}  # handle uid -> class name
         self._handles: Dict[int, Handle] = {}
+        # a vision engine's batches complete on the daemon's own
+        # completion thread (vision.Completions): flushes return once
+        # their batch is handed to it
+        self._completions = None
+        if not self._is_token:
+            from .vision import Completions
+            self._completions = Completions(engine)
 
     # -- lifecycle -----------------------------------------------------------
     def start(self) -> "ServingDaemon":
@@ -117,6 +131,8 @@ class ServingDaemon:
                     f"daemon already {self._state}: a ServingDaemon runs "
                     "one start/shutdown lifecycle")
             self._state = _RUNNING
+        if self._completions is not None:
+            self._completions.start()
         self._thread = threading.Thread(
             target=self._run, name="repro-serve", daemon=True)
         self._thread.start()
@@ -152,7 +168,10 @@ class ServingDaemon:
                 self._state = _STOPPING
             self._drain = False
             self._wake.notify_all()
-            return list(self._handles.values())
+            handles = list(self._handles.values())
+        if self._completions is not None:
+            self._completions.close(join=False)
+        return handles
 
     def shutdown(self, drain: bool = True,
                  timeout: Optional[float] = None) -> None:
@@ -176,6 +195,9 @@ class ServingDaemon:
                     self._drain = False
                     self._wake.notify_all()
                 self._thread.join()
+        if self._completions is not None:
+            # completes every batch in flight
+            self._completions.close()
         # cancel anything the loop did not serve (drain=False, or handles
         # still queued when a timed-out drain was demoted); in-flight
         # slots are dead with the loop, so cancel resolves them too
@@ -234,13 +256,15 @@ class ServingDaemon:
                                          preemptible=cls.preemptible, **kw)
                 handle = out.handle
             else:
-                out = self.engine.submit(payload, **kw)
+                # the batch this submit fills runs below, outside _wake
+                out = self.engine.submit(payload, poll=False, **kw)
                 handle = out
             t0 = self.engine.scheduler.now()
             cstats.submitted += 1
             self._outstanding[handle.uid] = cls.name
             self._handles[handle.uid] = handle
-            self._wake.notify_all()  # new work: wake a sleeping loop
+            if self._completions is None:
+                self._wake.notify_all()  # new work: wake a sleeping loop
 
         def _on_done(h: Handle, _cstats=cstats, _t0=t0) -> None:
             # completion latency (submit -> terminal) on the scheduler's
@@ -264,6 +288,14 @@ class ServingDaemon:
                 self._wake.notify_all()  # budget freed / drain progress
 
         handle.add_done_callback(_on_done)
+        if self._completions is not None:
+            # launch a batch this submit made due here, outside _wake (its
+            # flush may wait for room in flight, which only deliveries
+            # free, and they take _wake); then wake the loop for the rest
+            with self._completions.deferring():
+                self.engine.scheduler.poll()
+            with self._wake:
+                self._wake.notify_all()
         return out
 
     # -- the serve loop ------------------------------------------------------
@@ -274,8 +306,11 @@ class ServingDaemon:
             # count due queue work too: step() returns 0 when everything
             # just retired but more requests already wait
             return live or (1 if self.engine.scheduler.due() else 0)
-        resolved = self.engine.poll()
-        return resolved or (1 if self.engine.scheduler.due() else 0)
+        if self._completions.crashed is not None:
+            raise self._completions.crashed
+        with self._completions.deferring():
+            launched = self.engine.poll()
+        return launched or (1 if self.engine.scheduler.due() else 0)
 
     def _idle(self) -> bool:
         """Nothing queued and nothing in flight (drain-complete test)."""

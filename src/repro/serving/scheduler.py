@@ -488,7 +488,8 @@ class Scheduler:
 
     def submit(self, payload, deadline_ms: Optional[float] = None,
                priority: int = 0,
-               on_token: Optional[Callable[[int], None]] = None) -> Handle:
+               on_token: Optional[Callable[[int], None]] = None,
+               poll: bool = True) -> Handle:
         """Enqueue one request; returns its :class:`Handle` immediately.
 
         ``deadline_ms``: optional per-request deadline (relative to now);
@@ -497,6 +498,9 @@ class Scheduler:
         the default 0 preserves pure-FIFO behavior.
         ``on_token``: optional per-token streaming callback installed on
         the handle (invoked by the producer via ``Handle.push_token``).
+        ``poll``: with an executor, execute what is due before returning
+        (a now-full batch runs inline); ``poll=False`` leaves that to the
+        caller's own :meth:`poll`.
 
         Raises :class:`~repro.serving.errors.QueueFullError` when an
         :class:`OverloadPolicy` bounds the queue, it is full, and the
@@ -548,7 +552,7 @@ class Scheduler:
                     f"{self.overload.max_queue} and OverloadPolicy sheds "
                     "oldest"),
                 count_as="shed")
-        if self.executor is not None:
+        if poll and self.executor is not None:
             self.poll(now)  # a now-full batch executes inline
         return h
 

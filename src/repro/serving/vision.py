@@ -23,6 +23,12 @@ fused m2q/int8 matmul kernels, depthwise filters the packed-w4 conv kernel
 (kernels.ops.conv_dispatch_enabled), with the pure-XLA QTensor paths as
 fallback — no f32 dequantized-weight convolutions.
 
+Called directly, every call returns with its batch delivered.  Under a
+``ServingDaemon`` a batch is split in two halves (:class:`Completions`):
+the flushing thread stacks and copies it to the device and returns; the
+daemon's completion thread waits on it, fetches it and delivers it, so
+the host prepares the next batch while the device runs this one.
+
 Failure story (the fault-tolerance layer): executor exceptions fail ONLY
 the batch that was executing (the scheduler core contains them) and the
 engine keeps serving.  The jitted forward runs under a
@@ -37,8 +43,11 @@ at the ``vision`` / ``vision.kernel`` / ``executor`` sites.
 """
 from __future__ import annotations
 
+import collections
 import contextlib
+import contextvars
 import dataclasses
+import threading
 import time
 from typing import Callable, List, Optional
 
@@ -164,9 +173,11 @@ class VisionEngine:
         return pow2_bucket(n, self.min_bucket, self.B)
 
     # -- execution core ------------------------------------------------------
-    def _run_batch(self, images: np.ndarray, bucket: int) -> np.ndarray:
-        """Pad ``images`` (n <= bucket) up to ``bucket`` rows, run one
-        jitted forward, record batch stats, return the n real rows."""
+    def _run_batch(self, images: np.ndarray, bucket: int) -> "_Step":
+        """Pad ``images`` (n <= bucket) up to ``bucket`` rows and put them
+        on the device; returns the batch's :class:`_Step`, which
+        ``np.asarray`` turns into the n real logits rows (launching and
+        waiting on the jitted forward where nothing launched it yet)."""
         n = images.shape[0]
         pad = bucket - n
         if pad:
@@ -178,13 +189,38 @@ class VisionEngine:
             x = jnp.asarray(images)
             if self._batch_spec is not None:
                 x = jax.device_put(x, self._batch_spec)
-        with self._dispatch_scope():
-            # spans vision.launch and vision.sync
-            logits = self.fallback_guard.run(self._fwd, self.params, x)
-        self.stats.record_batch(items=n, padded=pad, capacity=self.B,
-                                bucket=bucket)
-        with tracing.span("vision.fetch"):
-            return np.asarray(logits)[:n]
+        return _Step(self, x, n, bucket)
+
+    def _prepare(self, handles: List[Handle]):
+        """The first half of a batch, on the thread that flushes it: the
+        ``vision`` fault site, the stack and the put.  Returns ``(step,
+        act)`` for :meth:`_complete`."""
+        act = (self.faults.on_call("vision")
+               if self.faults is not None else None)
+        if act is not None:
+            act.fire()  # raises/delays before any work runs
+        with tracing.span("vision.assemble"):
+            imgs = np.stack([h.payload for h in handles]).astype(np.float32)
+        return self._run_batch(imgs, self.bucket(len(handles))), act
+
+    def _complete(self, handles: List[Handle], step, act) -> None:
+        """The second half: wait on the forward (launching it first where
+        nothing did), fetch, poison where ``act`` says, and deliver."""
+        out = np.asarray(step)
+        if act is not None and act.poison:
+            # simulated silent corruption of the batch's outputs: poison
+            # ONE row — that request fails alone, batchmates deliver
+            out = out.copy()
+            out[0] = np.nan
+        with tracing.span("vision.deliver"):
+            for i, (h, row) in enumerate(zip(handles, out)):
+                if self.check_numerics and not np.all(np.isfinite(row)):
+                    h.set_exception(NumericalError(
+                        f"request {h.uid}: non-finite logits from the "
+                        f"vision forward (row {i} of the executed "
+                        "batch); its result was not delivered"))
+                else:
+                    h.set_result(row)
 
     def _execute(self, handles: List[Handle], reason: str) -> None:
         """Scheduler executor: one flushed batch -> per-handle logits.
@@ -196,45 +232,41 @@ class VisionEngine:
         guard's XLA retry) is contained by the scheduler core: it fails
         this batch's handles and the serving loop keeps running.
 
-        Spans: ``vision.batch`` over all of it; inside, in order,
-        ``vision.assemble`` (stack, dtype, padding), ``vision.put``,
-        ``vision.launch``, ``vision.sync``, ``vision.fetch`` and
-        ``vision.deliver`` (row checks, results, done-callbacks).
+        Called directly (``submit``, ``poll``, ``flush``, ``drain``), the
+        batch is delivered before this returns.  Spans: ``vision.batch``
+        over all of it; inside, in order, ``vision.assemble`` (stack,
+        dtype, padding), ``vision.put``, ``vision.launch``,
+        ``vision.sync``, ``vision.fetch`` and ``vision.deliver`` (row
+        checks, results, done-callbacks).  Under a :class:`Completions`
+        that defers this flush, ``vision.batch`` ends after the put (and
+        the launch, where an earlier batch is in flight) and the rest
+        runs on the completion thread.
         """
-        with tracing.span("vision.batch"):
-            act = (self.faults.on_call("vision")
-                   if self.faults is not None else None)
-            if act is not None:
-                act.fire()  # raises/delays before any work runs
-            with tracing.span("vision.assemble"):
-                imgs = np.stack([h.payload for h in handles]) \
-                    .astype(np.float32)
-            out = self._run_batch(imgs, self.bucket(len(handles)))
-            if act is not None and act.poison:
-                # simulated silent corruption of the batch's outputs:
-                # poison ONE row — that request fails alone, batchmates
-                # deliver
-                out = out.copy()
-                out[0] = np.nan
-            with tracing.span("vision.deliver"):
-                for i, (h, row) in enumerate(zip(handles, out)):
-                    if self.check_numerics and not np.all(np.isfinite(row)):
-                        h.set_exception(NumericalError(
-                            f"request {h.uid}: non-finite logits from the "
-                            f"vision forward (row {i} of the executed "
-                            "batch); its result was not delivered"))
-                    else:
-                        h.set_result(row)
+        comp = _deferring.get()
+        if comp is None or not comp.reserve(self):
+            with tracing.span("vision.batch"):
+                self._complete(handles, *self._prepare(handles))
+            return
+        try:
+            with tracing.span("vision.batch"):
+                comp.hand(handles, *self._prepare(handles))
+        except BaseException:
+            comp.release()
+            raise
 
     # -- request API ---------------------------------------------------------
     def submit(self, image: np.ndarray,
-               deadline_ms: Optional[float] = None) -> Handle:
+               deadline_ms: Optional[float] = None,
+               poll: bool = True) -> Handle:
         """Queue one (H, W, 3) image; returns a handle whose ``result()``
         (this image's (n_classes,) logits) is delivered at flush — when the
         batch fills, the deadline fires, or ``flush()`` drains.
 
         ``deadline_ms``: optional per-request deadline — a queued request
         that is not executed within that many ms ends ``TIMED_OUT``.
+        ``poll=False``: only enqueue; a batch this submit fills runs at
+        the caller's next :meth:`poll` (the serving daemon polls outside
+        its own lock).
 
         Raises ``ValueError`` on malformed payloads, validated UP FRONT so
         bad inputs fail here with a clear message, not as a poisoned batch
@@ -259,7 +291,8 @@ class VisionEngine:
                 raise ValueError(
                     "image holds NaN/Inf pixels; refusing to enqueue a "
                     "payload that would poison its whole executed batch")
-        return self.scheduler.submit(img, deadline_ms=deadline_ms)
+        return self.scheduler.submit(img, deadline_ms=deadline_ms,
+                                     poll=poll)
 
     def poll(self) -> int:
         """Execute whatever the flush policy says is due (a full batch, or
@@ -267,6 +300,8 @@ class VisionEngine:
         of requests RESOLVED — delivered or failed: executor exceptions
         fail only their batch's handles (each handle's ``result()``
         re-raises), never this call, so serving loops keep polling.
+        (Under a deferring :class:`Completions`, the requests launched:
+        their completion thread resolves them.)
         ``scheduler.next_deadline()`` says how long they may sleep first."""
         self.heartbeat = time.monotonic()
         return self.scheduler.poll()
@@ -295,8 +330,179 @@ class VisionEngine:
         outs = []
         for start in range(0, n, self.B):
             chunk = images[start:start + self.B]
-            outs.append(self._run_batch(chunk, self.bucket(chunk.shape[0])))
+            outs.append(np.asarray(
+                self._run_batch(chunk, self.bucket(chunk.shape[0]))))
             # keep sum(flush_reasons) == batches across mixed direct/queued
             # use (queued flushes record their reason in Scheduler.pop)
             self.stats.record_flush("direct")
         return np.concatenate(outs)
+
+
+class _Step:
+    """One padded batch put on the device (``VisionEngine._run_batch``).
+
+    :meth:`launch` calls the jitted forward under the engine's fallback
+    guard and returns without waiting on the device.  ``np.asarray``
+    launches it where nothing did yet, waits on it (the guard's finite
+    check, with its XLA retry), records the batch and fetches the n real
+    rows, cut on the host: no slice of a varying n is ever compiled.
+    """
+
+    __slots__ = ("engine", "x", "n", "bucket", "_launched", "_rows")
+
+    def __init__(self, engine: VisionEngine, x, n: int, bucket: int):
+        self.engine = engine
+        self.x = x
+        self.n = n
+        self.bucket = bucket
+        self._launched = None   # the guard's (out, flags)
+        self._rows: Optional[np.ndarray] = None
+
+    def launch(self) -> None:
+        if self._launched is None:
+            eng = self.engine
+            with eng._dispatch_scope():
+                # span vision.launch
+                self._launched = eng.fallback_guard.launch(
+                    eng._fwd, eng.params, self.x)
+
+    def __array__(self, dtype=None, copy=None) -> np.ndarray:
+        if self._rows is None:
+            self.launch()
+            eng = self.engine
+            with eng._dispatch_scope():
+                # span vision.sync, and vision.launch for a retry
+                logits = eng.fallback_guard.finish(
+                    *self._launched, eng._fwd, eng.params, self.x)
+            eng.stats.record_batch(items=self.n, padded=self.bucket - self.n,
+                                   capacity=eng.B, bucket=self.bucket)
+            with tracing.span("vision.fetch"):
+                self._rows = np.asarray(logits)[:self.n]
+            self.x = self._launched = None
+        rows = self._rows if dtype is None else self._rows.astype(dtype)
+        return rows.copy() if copy else rows
+
+
+# the Completions whose deferring() block this thread is in
+_deferring: contextvars.ContextVar[Optional["Completions"]] = \
+    contextvars.ContextVar("vision_deferring", default=None)
+
+
+class Completions:
+    """The completion thread (``repro-complete``) that a
+    :class:`~repro.serving.daemon.ServingDaemon` owns for its vision
+    engine, so that the host prepares the next batch while the device
+    runs the last.
+
+    A flush made inside :meth:`deferring` runs only the first half of its
+    batch on the flushing thread (the ``vision`` fault site, the stack
+    and the put) and hands the batch here.  This thread completes batches
+    in the order they were handed, each under the span
+    ``vision.complete``: it waits on the oldest (the guard's finite
+    check, with its XLA retry), fetches it and delivers it.  Where an
+    earlier batch is still in flight, the flushing thread launches the
+    forward itself, so that the step queues on the device behind the
+    running one (``ServeStats.overlapped_batches`` counts these);
+    otherwise this thread, which would only wait for it, launches it.
+    At most ``DEPTH`` batches are in flight: a flush that would make a
+    third waits until the oldest is completed.  An exception in the
+    second half fails that batch's handles alone.
+    """
+
+    DEPTH = 2   # one batch running on the device, one queued behind it
+
+    def __init__(self, engine: VisionEngine):
+        self.engine = engine
+        self.max_depth = 0   # the most batches that were in flight at once
+        self.crashed: Optional[BaseException] = None
+        self._cond = threading.Condition()
+        self._queue: collections.deque = collections.deque()
+        self._depth = 0      # batches reserved and not yet completed
+        self._open = True
+        self._thread = threading.Thread(target=self._run,
+                                        name="repro-complete", daemon=True)
+
+    def start(self) -> "Completions":
+        self._thread.start()
+        return self
+
+    def close(self, join: bool = True) -> None:
+        """Take no more batches (later flushes complete on their own
+        thread); the thread completes every batch it holds, then ends.
+        ``join``: wait for that."""
+        with self._cond:
+            self._open = False
+            self._cond.notify_all()
+        if join and self._thread.is_alive() \
+                and self._thread is not threading.current_thread():
+            self._thread.join()
+
+    @contextlib.contextmanager
+    def deferring(self):
+        """Flushes of this engine made on this thread inside the block
+        hand their second half to the completion thread."""
+        token = _deferring.set(self)
+        try:
+            yield
+        finally:
+            _deferring.reset(token)
+
+    def reserve(self, engine: VisionEngine) -> bool:
+        """Room for one more of ``engine``'s batches in flight, waiting
+        for it; False where the batch completes on the caller's thread:
+        another engine's, the thread closed, or the caller is the
+        completion thread itself (a done-callback that submits)."""
+        if engine is not self.engine \
+                or threading.current_thread() is self._thread:
+            return False
+        with self._cond:
+            self._cond.wait_for(
+                lambda: self._depth < self.DEPTH or not self._open)
+            if not self._open:
+                return False
+            self._depth += 1
+            self.max_depth = max(self.max_depth, self._depth)
+            return True
+
+    def release(self) -> None:
+        """Give back a reservation whose batch failed before :meth:`hand`."""
+        with self._cond:
+            self._depth -= 1
+            self._cond.notify_all()
+
+    def hand(self, handles: List[Handle], step, act) -> None:
+        """Queue a reserved batch for completion; launch it first where an
+        earlier batch is still in flight."""
+        with self._cond:
+            behind = bool(self._queue)
+        # (a fault wrapped around _run_batch may hand back finished rows)
+        if behind and isinstance(step, _Step):
+            step.launch()
+            self.engine.stats.record_overlap()
+        with self._cond:
+            self._queue.append((handles, step, act))
+            self._cond.notify_all()
+
+    def _run(self) -> None:
+        try:
+            while True:
+                with self._cond:
+                    self._cond.wait_for(
+                        lambda: self._queue
+                        or (not self._open and not self._depth))
+                    if not self._queue:
+                        return
+                    handles, step, act = self._queue[0]
+                try:
+                    with tracing.span("vision.complete"):
+                        self.engine._complete(handles, step, act)
+                except Exception as e:  # noqa: BLE001 — fails its batch
+                    for h in handles:
+                        h.set_exception(e)
+                with self._cond:
+                    self._queue.popleft()
+                    self._depth -= 1
+                    self._cond.notify_all()
+        except BaseException as e:  # noqa: BLE001 — the daemon re-raises
+            self.crashed = e
+            self.close(join=False)
