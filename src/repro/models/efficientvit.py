@@ -10,6 +10,15 @@ B1: widths (16,32,64,128,256), depths (1,2,3,3,4); B2: widths
 (24,48,96,192,384), depths (1,3,4,4,6).  Norms are channel LayerNorms
 (functional stand-in for BN; noted in DESIGN.md), activation is Hardswish->
 we use SiLU (same family).  NHWC layout throughout.
+
+The block graph is not upstream's ``EfficientViTBackbone``
+(mit-han-lab/efficientvit): there stage 0 is DSConv blocks (expand 1),
+stages 3-4 are one stride-2 MBConv then ``d`` EfficientViT blocks (LiteMLA,
+then an MBConv), LiteMLA's aggregation is a 5x5 depthwise conv followed by
+a grouped 1x1 conv (groups = 3 x heads), and no norm precedes qkv.  Here
+every stage is MBConv at expand 4, stages 3-4 are ``d`` (MBConv, MSA)
+pairs, the aggregation is the depthwise conv alone, and an RMS norm
+precedes qkv.
 """
 from __future__ import annotations
 
@@ -175,8 +184,10 @@ def forward(cfg: ArchConfig, params, images, unroll: bool = False,
     """images: (B, res, res, 3) -> logits (B, n_classes).
 
     Each part runs under a ``jax.named_scope`` (``stem``, ``stage0``...,
-    ``head``), which the compiled program keeps in every instruction's
-    ``op_name`` metadata: a profile's device ops map back to their stage.
+    ``head``), and inside a stage each block under ``mbconv`` or ``msa``;
+    the compiled program keeps them in every instruction's ``op_name``
+    metadata (``stage3/msa/...``): a profile's device ops map back to their
+    stage and block kind.
     """
     dtype = jnp.dtype(cfg.dtype)
     with jax.named_scope("stem"):
@@ -187,9 +198,11 @@ def forward(cfg: ArchConfig, params, images, unroll: bool = False,
         with jax.named_scope(f"stage{si}"):
             for bi, blk in enumerate(blocks):
                 stride = 2 if (bi == 0 and si > 0) else 1
-                x = _mbconv(blk["mb"], x, stride=stride)
+                with jax.named_scope("mbconv"):
+                    x = _mbconv(blk["mb"], x, stride=stride)
                 if "msa" in blk:
-                    x = _msa(blk["msa"], x, cfg.dim_per_head)
+                    with jax.named_scope("msa"):
+                        x = _msa(blk["msa"], x, cfg.dim_per_head)
     with jax.named_scope("head"):
         x = nn.conv2d(x, params["head"]["w_in"])
         x = nn.silu(_cln(x, params["head"]["ln"]))

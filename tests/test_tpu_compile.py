@@ -146,3 +146,53 @@ def test_b1_served_forward_compiles_with_every_kernel(one_chip,
     # convs, 2 token scales x 7 MSA blocks)
     assert counts == {k: int(np.sum([r.kernel == k for r in reqs]))
                       for k in dispatched}
+
+
+def test_b2_served_forward_compiles_and_block_scopes_change_nothing(
+        one_chip, no_persistent_cache, monkeypatch):
+    """The quantized B2 R224 forward at bucket 16, as the b2-int8-offline
+    cell serves it (uniform int8 compute layers): 32-wide heads and
+    1,536-channel expansions compile with every kernel, and the forward's
+    ``jax.named_scope``s (stage, then ``mbconv`` / ``msa``) change no
+    instruction and no kernel launch, only the metadata."""
+    import contextlib
+    import dataclasses
+    import re
+
+    from repro.configs.registry import ARCHS
+    from repro.models import get_model
+    from repro.recipe import PRESETS, abstract_quantize
+
+    monkeypatch.setattr(ops, "_interpret_default", lambda: False)
+    cfg = ARCHS["efficientvit-b2-r224"]
+    model = get_model(cfg)
+    base = PRESETS["m2q-w8a8"]
+    recipe = base.replace(policy=dataclasses.replace(
+        base.policy, compute_scheme="uniform8"))
+    qp = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        abstract_quantize(cfg, recipe=recipe, tokens_per_step=16))
+    x = jax.ShapeDtypeStruct((16, 224, 224, 3), f32, sharding=one_chip)
+
+    def compiled():
+        with ops.dispatch(dense=True, conv=True, attn=True):
+            return jax.jit(lambda p, im: model.forward(cfg, p, im)).lower(
+                qp, x).compile().as_text()
+
+    def instructions(text):
+        return re.findall(r"^\s*(?:ROOT\s+)?%(\S+)\s*=\s*(\S+ \S+?)\(",
+                          text, re.M)
+
+    scoped = compiled()
+    # 18 MBConvs (two matmuls, one depthwise conv), 10 MSA blocks (qkv and
+    # proj matmuls, the 5x5 depthwise conv, two token scales), two in the
+    # head
+    assert kernel_counts(scoped) == {"int8_matmul": 58, "dwconv_w4": 28,
+                                     "relu_attn": 20}
+    assert "stage3/msa/" in scoped and "stage4/mbconv/" in scoped
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    bare = compiled()
+    assert "stage3/" not in bare
+    assert instructions(scoped) == instructions(bare)
+    assert kernel_counts(bare) == kernel_counts(scoped)
