@@ -26,9 +26,9 @@ ZERO probes.
 
 Interpret-safe fallback: on CPU / interpret mode (the container has no TPU)
 timing the Python interpreter is meaningless, so on a cache miss the
-heuristic triple is returned immediately and nothing is benchmarked or
-persisted.  Writes are atomic (tmp + rename) so concurrent processes never
-observe a torn file.
+fallback triple (:func:`fallback_blocks`) is returned immediately and
+nothing is benchmarked or persisted.  Writes are atomic (tmp + rename) so
+concurrent processes never observe a torn file.
 
 A corrupt cache file NEVER takes the process down: truncated JSON, a
 non-dict top level, entries that are not three ints, or keys that do not
@@ -64,9 +64,12 @@ _CACHES: Dict[str, "AutotuneCache"] = {}
 # entries (tuned for the old grid) then miss instead of mis-steering the
 # rewritten kernel.  dwconv_w4 is v2: the H-tiled (B, H-tiles, C-blocks)
 # grid replaced the whole-map (B, C-blocks) grid in PR 9.
+# int8_matmul is v2: row slabs of whole K and N (ops.int8_tile_plan)
+# replaced 128-wide blocks, and the kernel reads and writes the
+# activation dtype.
 KERNEL_VERSIONS: Dict[str, int] = {
     "m2q_matmul": 1,
-    "int8_matmul": 1,
+    "int8_matmul": 2,
     "int4_matmul": 1,
     "apot_matmul": 1,
     "dwconv_w4": 2,
@@ -110,6 +113,22 @@ def heuristic_block(m: int, cap: int = 128) -> int:
 def heuristic_blocks(M: int, N: int, K: int, cap: int = 128) -> Blocks:
     return (heuristic_block(M, cap), heuristic_block(N, cap),
             heuristic_block(K, cap))
+
+
+def fallback_blocks(kernel: str, M: int, N: int, K: int,
+                    meta: Optional[dict] = None) -> Blocks:
+    """The triple a launch runs where nothing is cached or timed.
+
+    ``int8_matmul``: the VMEM row-slab plan (``ops.int8_tile_plan``) over
+    the rows each device holds, in the activation dtype (``meta`` keys
+    ``row_shards`` and ``x_bytes``, default one device and f32).  Every
+    other kernel: :func:`heuristic_blocks`."""
+    if kernel != "int8_matmul":
+        return heuristic_blocks(M, N, K)
+    from .ops import int8_tile_plan  # ops imports this module
+    m = dict(meta or {})
+    return int8_tile_plan(-(-M // m.get("row_shards", 1)), K, N,
+                          m.get("x_bytes", 4))
 
 
 def candidate_blocks(M: int, N: int, K: int) -> List[Blocks]:
@@ -357,26 +376,27 @@ def blocks_for(kernel: str, M: int, N: int, K: int, *,
     """Resolve the block triple for one kernel launch.
 
     Lookup order: persistent cache (warmed offline by the sweep, or by a
-    previous lazy tune on this backend) -> live tuning -> heuristic.  The
+    previous lazy tune on this backend) -> live tuning ->
+    :func:`fallback_blocks`, which live tuning also times.  The
     cache is consulted FIRST on every backend — a committed cache serves
     its block choices even where tuning is disabled.  Tuning only happens
     on a real accelerator backend (or when ``force_tune`` is set, for
     tests) AND when a ``bench_fn`` is provided; every other case falls
-    back to the heuristic so the interpret path stays cheap and
+    back to :func:`fallback_blocks` so the interpret path stays cheap and
     deterministic.  Every call is visible to :func:`record_requests` (the
     offline sweep's shape discovery), including calls made while tracing.
     ``operands``: the launch's array arguments; if any is a tracer the call
     is under a jit/vmap trace and nothing is timed or persisted.
     """
     _record(kernel, M, N, K, tunable=True, meta=meta)
-    fallback = heuristic_blocks(M, N, K)
+    fallback = fallback_blocks(kernel, M, N, K, meta)
     key = cache_key(kernel, M, N, K)
     cache = _shared_cache(cache_path)
     if any(isinstance(a, jax.core.Tracer)
            for a in jax.tree_util.tree_leaves(operands)):
         # inside a jit/vmap trace the bench closure holds tracers:
         # "timing" it measures Python tracing, not the kernel.  Use the
-        # cache if warm, else the heuristic — and never persist from here.
+        # cache if warm, else the fallback — and never persist from here.
         return cache.get(key) or fallback
     hit = cache.get(key)
     if hit is not None and not force_tune:
@@ -385,7 +405,8 @@ def blocks_for(kernel: str, M: int, N: int, K: int, *,
                              and jax.default_backend() != "cpu")
     if not tunable or bench_fn is None:
         return fallback
-    cands = list(candidates) if candidates else candidate_blocks(M, N, K)
+    cands = list(candidates) if candidates else \
+        sorted(set(candidate_blocks(M, N, K)) | {fallback})
     timed = [(_time_candidate(bench_fn, c), c) for c in cands]
     timed.sort(key=lambda t: (t[0], t[1]))
     if not timed or timed[0][0] == float("inf"):

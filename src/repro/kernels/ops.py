@@ -9,7 +9,9 @@ Block sizes come from the shape-keyed autotuner (kernels.autotune): the
 persistent per-backend cache is consulted FIRST (warmed offline by
 ``launch/autotune_sweep.py`` so serving traces are pure cache hits); on a
 cache miss a real accelerator times candidates once and persists the
-winner, while CPU/interpret falls back to the power-of-two heuristic.
+winner, while CPU/interpret and traces fall back to
+``autotune.fallback_blocks``: :func:`int8_tile_plan`'s row slabs for
+``int8_matmul``, the power-of-two heuristic for the other kernels.
 
 The M2Q path is permutation-free end to end: the merged byte payload is in
 original filter order, the fused kernel emits ONE output array, and the old
@@ -243,6 +245,16 @@ def axis_tripped(axis: str) -> bool:
 def trip_counts() -> dict:
     """Per-axis trip counters (how often a FallbackGuard latched each)."""
     return dict(_TRIP_LATCH)
+
+
+_INT8_PLANS: dict = {"slab": 0, "tiled": 0}
+
+
+def int8_plan_counts() -> dict:
+    """``int8_matmul`` launches traced (or run eagerly) in this process, by
+    plan: ``slab`` takes whole K and N in each row-slab grid step,
+    ``tiled`` blocks N or K (see :func:`int8_tile_plan`)."""
+    return dict(_INT8_PLANS)
 
 
 def reset_trip_latch() -> None:
@@ -513,7 +525,7 @@ def _int8_core(x, wq, act_scale, scale, zero_point, bm, bn, bk, interpret,
     M, K = x.shape
     N = wq.shape[1]
     bn, bk = _tile(bn, N, 128), _tile(bk, K, 128)
-    xp = _pad2(x.astype(jnp.float32), bm * _row_shards(mesh), bk)
+    xp = _pad2(x, bm * _row_shards(mesh), bk)
     wp = _pad2(wq, bk, bn)
     y = _on_mesh(mesh, partial(int8_matmul, bm=bm, bn=bn, bk=bk,
                                interpret=interpret),
@@ -525,7 +537,9 @@ def _int8_core(x, wq, act_scale, scale, zero_point, bm, bn, bk, interpret,
 def int8_matmul_op(x, wq, act_scale, scale, zero_point,
                    interpret: Optional[bool] = None,
                    blocks: Optional[Tuple[int, int, int]] = None):
-    """x (M,K) FLOAT activations; quantization is fused into the kernel."""
+    """x (M,K) FLOAT activations, read in their own dtype; quantization is
+    fused into the kernel; y (M,N) in x's dtype.  Blocks: the autotune
+    cache, else :func:`int8_tile_plan` (``autotune.fallback_blocks``)."""
     interpret = _interpret_default() if interpret is None else interpret
     mesh = _KERNEL_MESH.get()
     M, K = x.shape
@@ -533,8 +547,12 @@ def int8_matmul_op(x, wq, act_scale, scale, zero_point,
     if blocks is None:
         blocks = autotune.blocks_for(
             "int8_matmul", M, N, K, interpret=interpret, operands=(x,),
+            meta={"x_bytes": x.dtype.itemsize,
+                  "row_shards": _row_shards(mesh)},
             bench_fn=lambda b: _int8_core(x, wq, act_scale, scale, zero_point,
                                           *b, interpret, mesh))
+    whole = _tile(blocks[1], N, 128) >= N and _tile(blocks[2], K, 128) >= K
+    _INT8_PLANS["slab" if whole else "tiled"] += 1
     return _int8_core(x, wq, act_scale, scale, zero_point, *blocks, interpret,
                       mesh)
 
@@ -670,7 +688,8 @@ def _dwconv_bc(bn: int, C: int) -> int:
 # the footprint is bounded by the TILE, not the feature map: one halo'd
 # input slab (bh_in x WI x bc f32), one output slab (bh x WO x bc f32), and
 # the decoded weight tile.  8 MiB leaves headroom in a 16 MiB-class VMEM for
-# double-buffered pipelining of the next slab.
+# double-buffered pipelining of the next slab.  int8_tile_plan counts
+# int8_matmul's row slabs against the same budget.
 _DWCONV_VMEM_BYTES = 8 * 1024 * 1024
 
 
@@ -711,6 +730,62 @@ def dwconv_tile_plan(H: int, W: int, kh: int, kw: int, stride: int,
     if _dwconv_tile_bytes(W, kh, kw, stride, bh, bc) > budget:
         return None
     return bh, bc
+
+
+def _int8_tile_bytes(bm: int, bn: int, bk: int, x_bytes: int) -> int:
+    """VMEM bytes one int8_matmul grid step holds: the x, weight, scale,
+    zero-point and output (x's dtype) blocks double-buffered, the int32
+    accumulator and row sums, each padded to the (8, 128) tile of 32-bit
+    data ((16, 128) at 16 bits, (32, 128) at 8)."""
+    def tile(rows: int, cols: int, nbytes: int) -> int:
+        return _round_up(rows, 32 // nbytes) * _round_up(cols, 128) * nbytes
+
+    return (2 * (tile(bm, bk, x_bytes) + tile(bk, bn, 1) + tile(1, 1, 4)
+                 + 2 * tile(1, bn, 4) + tile(bm, bn, x_bytes))
+            + tile(bm, bn, 4) + tile(bm, 1, 4))
+
+
+def _lane_blocks(n: int) -> list:
+    """Blocks along a lane dim of size ``n``, largest first: all of it,
+    then the multiples of 128 that tile ``n`` rounded up to 128."""
+    t = -(-n // 128)
+    return [n] + [128 * d for d in range(t - 1, 0, -1) if t % d == 0]
+
+
+def int8_tile_plan(M: int, K: int, N: int, x_bytes: int,
+                   budget: int = _DWCONV_VMEM_BYTES
+                   ) -> Tuple[int, int, int]:
+    """int8_matmul's blocks ``(bm, bn, bk)`` for an (M, K) x (K, N) launch
+    whose x and y take ``x_bytes`` an element.
+
+    Whole K and N (one weight block, fetched once) unless even the smallest
+    row block beside them overflows ``budget``; then N, and after it K,
+    take the largest multiple of 128 that fits and tiles them.  Rows: all
+    of M if it fits, else the largest block in multiples of the x
+    sublane tile that fits and divides M in at most four times the fewest
+    steps; where none does, the fewest steps, with M padded up to them."""
+    sub = 32 // x_bytes
+
+    def nbytes(bm: int) -> int:
+        return _int8_tile_bytes(bm, bn, bk, x_bytes)
+
+    bk = K
+    for bn in _lane_blocks(N):
+        if nbytes(min(M, sub)) <= budget:
+            break
+    for bk in _lane_blocks(K):
+        if nbytes(min(M, sub)) <= budget:
+            break
+    if nbytes(M) <= budget:
+        return M, bn, bk
+    # bytes grow by the same amount per ``sub`` rows
+    per_sub = nbytes(2 * sub) - nbytes(sub)
+    rows = sub * (1 + max(0, budget - nbytes(sub)) // per_sub)
+    least = -(-M // rows)
+    for steps in range(least, 4 * least + 1):
+        if M % steps == 0 and (M // steps) % sub == 0:
+            return M // steps, bn, bk
+    return _round_up(-(-M // least), sub), bn, bk
 
 
 def dwconv_w4_op(x, packed, scale, zero_point, kh: int = 3, kw: int = 3,
@@ -857,7 +932,9 @@ def decode_attn_int8_op(q, k_q, v_q, k_scale, v_scale, lengths,
 def qtensor_matmul(x: jax.Array, qt, interpret: Optional[bool] = None):
     """Kernel-backed y = x @ W for 2-D QTensor leaves; x (..., K)."""
     lead = x.shape[:-1]
-    x2 = x.reshape(-1, x.shape[-1]).astype(jnp.float32)
+    x2 = x.reshape(-1, x.shape[-1])
+    if not (isinstance(qt, QUniform) and qt.bits == 8):
+        x2 = x2.astype(jnp.float32)  # int8_matmul reads x's own dtype
     if isinstance(qt, (QM2Q, QExpertM2Q)):
         sa = _act_scale_or_default(x2, qt.act_scale)
         y = m2q_matmul_op(x2, sa, qt.payload, qt.u_scale.reshape(-1),

@@ -12,7 +12,7 @@ cache file that ``kernels.autotune`` consults FIRST on every launch.
 
 On an accelerator each shape is tuned against synthetic operands (the
 request's recorded geometry rebuilds a real launch); on CPU/interpret —
-where timing the Python interpreter is meaningless — the heuristic triple
+where timing the Python interpreter is meaningless — the fallback triple
 is committed instead, which is byte-identical to what lazy tuning would
 have chosen there (the offline-vs-lazy equivalence tests pin this).
 
@@ -70,7 +70,8 @@ def _bench_fn(req, interpret: bool) -> Optional[Callable]:
             x, jnp.float32(1.0), payload, v1, v0, v1,
             interpret=interpret, blocks=b)
     if req.kernel == "int8_matmul":
-        x = jnp.ones((M, K), jnp.float32)
+        x = jnp.ones((M, K), jnp.bfloat16 if meta.get("x_bytes") == 2
+                     else jnp.float32)
         wq = jnp.zeros((K, N), jnp.int8)
         v1 = jnp.ones((N,), jnp.float32)
         v0 = jnp.zeros((N,), jnp.float32)
@@ -129,7 +130,7 @@ def discover(configs: Sequence[str], recipes: Sequence[str],
 
 def warm(requests, cache_path: str, *, force_tune: bool = False,
          progress=print) -> Tuple[int, int]:
-    """Tune (accelerator) or heuristically seed (CPU) every tunable
+    """Tune (accelerator) or seed with the fallback (CPU) every tunable
     request into ``cache_path``.  Returns (written, skipped-as-cached)."""
     import jax
 
@@ -154,8 +155,9 @@ def warm(requests, cache_path: str, *, force_tune: bool = False,
         else:
             # CPU: candidate timing measures the Python interpreter, so
             # commit what lazy tuning would have chosen here — the
-            # heuristic (byte-identical by the equivalence tests)
-            blocks = autotune.heuristic_blocks(req.M, req.N, req.K)
+            # fallback (byte-identical by the equivalence tests)
+            blocks = autotune.fallback_blocks(req.kernel, req.M, req.N,
+                                              req.K, dict(req.meta))
         cache.put(key, blocks, save=False)
         wrote += 1
         progress(f"  {key:<52} -> {tuple(blocks)}")
@@ -215,7 +217,8 @@ def bench_rows(requests, cache_path: str, limit: int,
         if fn is None:
             continue
         blocks = (cache.get(req.key())
-                  or autotune.heuristic_blocks(req.M, req.N, req.K))
+                  or autotune.fallback_blocks(req.kernel, req.M, req.N,
+                                              req.K, dict(req.meta)))
         t = autotune.measure(fn, tuple(blocks), reps=2)
         rows.append({"name": f"{req.kernel}:{req.M}x{req.N}x{req.K}",
                      "kernel": req.kernel, "blocks": list(blocks),

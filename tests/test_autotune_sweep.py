@@ -216,3 +216,42 @@ def test_sweep_discovers_warms_and_smokes(tmp_path, monkeypatch):
     autotune._CACHES.pop(path, None)  # drop the warmed in-process view
     assert sw.smoke(cfg, rec, path, hires=(),
                     progress=lambda *a: None) == 1
+
+
+def test_int8_launches_against_a_warmed_v2_cache_run_zero_probes(
+        tmp_path, monkeypatch):
+    """After the int8_matmul version bump a cache warmed from the served
+    requests covers them under v2 keys: the traced launch serves the slab
+    plan from it and runs zero probes; a v1 entry for the shape is never
+    looked up."""
+    from repro.kernels import ops
+
+    path = str(tmp_path / "cpu.json")
+    monkeypatch.setenv("REPRO_AUTOTUNE_CACHE", path)
+    M, K, N = 64, 96, 24
+    x = jax.ShapeDtypeStruct((M, K), jnp.bfloat16)
+    w = jax.ShapeDtypeStruct((K, N), jnp.int8)
+    v = jax.ShapeDtypeStruct((N,), jnp.float32)
+
+    def launch(x, w, v):
+        return ops.int8_matmul_op(x, w, jnp.float32(1.0), v, v)
+
+    reqs = []
+    with autotune.record_requests(reqs):
+        jax.eval_shape(launch, x, w, v)
+    assert [(r.kernel, r.M, r.N, r.K) for r in reqs] == \
+        [("int8_matmul", M, N, K)]
+    assert dict(reqs[0].meta)["x_bytes"] == 2
+    autotune.AutotuneCache(path).put(
+        f"int8_matmul@v1:{M}x{N}x{K}:{jax.default_backend()}", (8, 8, 8))
+    sw.warm(reqs, path, progress=lambda *a: None)
+    cache = autotune.AutotuneCache(path).load()
+    assert cache.get(reqs[0].key()) == ops.int8_tile_plan(M, K, N, 2)
+    assert "@v2:" in reqs[0].key()
+    autotune._CACHES.pop(path, None)
+    autotune.reset_probe_count()
+    served = []
+    with autotune.record_requests(served):  # a fresh trace, not eval_shape's
+        jax.jit(lambda *a: launch(*a)).lower(x, w, v)
+    assert autotune.tuning_probe_count() == 0
+    assert [r.key() for r in served] == [reqs[0].key()]

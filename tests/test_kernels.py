@@ -288,15 +288,159 @@ def test_dense_routes_qtensors_through_kernels_when_enabled(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
+# int8_matmul launch geometry: row slabs of whole K and N, bf16 at HBM
+# ---------------------------------------------------------------------------
+
+# EfficientViT-B2's 1x1 convs (K, N) at reduced M, a row count with no
+# 16-row divisor (B2 stage 4 at batch 15: the padded plan), the int8 head,
+# and a qwen1.5-0.5b decode FFN at batch 4
+SLAB_CASES = [(1024, 24, 96), (1024, 96, 24), (1024, 48, 192),
+              (1024, 192, 48), (1024, 384, 1152), (1024, 768, 384),
+              (1024, 384, 1536), (735, 384, 1536), (16, 1536, 1000),
+              (4, 1024, 2816)]
+
+
+@pytest.mark.parametrize("M,K,N", SLAB_CASES)
+def test_int8_slab_path_is_bit_identical_to_the_padded_f32_path(M, K, N):
+    """The served 8-bit path (bf16 into and out of the kernel, blocks from
+    int8_tile_plan) gives the same bits as the path it replaced: x cast to
+    f32, padded 128-wide blocks, an f32 output, then the cast to bf16."""
+    rng = _rng(M * 7 + K + N)
+    qt = _mk_int8_weights(rng, K, N)
+    x = jnp.asarray(rng.normal(0, 1, (M, K)), jnp.bfloat16)
+    qt.act_scale = jnp.max(jnp.abs(x.astype(jnp.float32))) / 127.0
+    bm, bn, bk = ops.int8_tile_plan(M, K, N, 2)
+    assert (bn, bk) == (N, K)
+    new = ops.qtensor_matmul(x, qt, interpret=True)
+    old = ops.int8_matmul_op(
+        x.astype(jnp.float32), qt.payload, qt.act_scale,
+        qt.scale.reshape(-1), qt.zero_point.reshape(-1), interpret=True,
+        blocks=(128, 128, 128)).astype(jnp.bfloat16)
+    assert new.dtype == jnp.bfloat16 and new.shape == (M, N)
+    np.testing.assert_array_equal(np.asarray(new.view(jnp.uint16)),
+                                  np.asarray(old.view(jnp.uint16)))
+
+
+def _pointwise_launches(arch):
+    """Every int8_matmul launch (M, N, K) of the quantized forward of
+    ``arch`` at one image, as the served trace requests it, and the
+    int8_plan_counts() the trace added."""
+    import dataclasses
+
+    from repro.configs.registry import ARCHS
+    from repro.models import get_model
+    from repro.recipe import PRESETS, abstract_quantize
+
+    cfg = ARCHS[arch]
+    base = PRESETS["m2q-w8a8"]
+    recipe = base.replace(policy=dataclasses.replace(
+        base.policy, compute_scheme="uniform8"))
+    qp = abstract_quantize(cfg, recipe=recipe, tokens_per_step=16)
+    x = jax.ShapeDtypeStruct((1, 224, 224, 3), jnp.float32)
+    reqs, before = [], ops.int8_plan_counts()
+    with autotune.record_requests(reqs), \
+            ops.dispatch(dense=True, conv=True, attn=True):
+        jax.eval_shape(lambda p, im: get_model(cfg).forward(cfg, p, im),
+                       qp, x)
+    after = ops.int8_plan_counts()
+    return ([(r.M, r.N, r.K) for r in reqs if r.kernel == "int8_matmul"],
+            {k: after[k] - before[k] for k in after})
+
+
+@pytest.fixture(scope="module")
+def pointwise():
+    return {arch: _pointwise_launches(arch)
+            for arch in ("efficientvit-b1-r224", "efficientvit-b2-r224")}
+
+
+def test_int8_plan_takes_whole_k_and_n_at_every_bucket(pointwise):
+    """Every B1 and B2 1x1 conv (and head) at buckets 1-16 gets one weight
+    block of whole K and N and a row slab that fits the VMEM budget as
+    counted.  Rows divide M at bucket 16, the offline cells' batch; where
+    M = b x 196 or b x 49 has no 16-row divisor (odd b), M is padded by
+    under one sublane tile a step."""
+    budget = ops._DWCONV_VMEM_BYTES
+    for arch, (launches, _) in pointwise.items():
+        steps16 = 0
+        for M1, N, K in launches:
+            for b in range(1, 17):
+                M = b * M1
+                bm, bn, bk = ops.int8_tile_plan(M, K, N, 2)
+                assert (bn, bk) == (N, K), (arch, M, K, N)
+                assert ops._int8_tile_bytes(bm, bn, bk, 2) <= budget
+                assert bm == M or bm % 16 == 0, (arch, M, K, N, bm)
+                steps = -(-M // bm)
+                assert steps * bm - M < 16 * steps, (arch, M, K, N, bm)
+                if b == 16:
+                    assert M % bm == 0, (arch, M, K, N, bm)
+                    steps16 += steps
+        # 19,908 grid steps a B2 forward at 128-row blocks
+        assert steps16 < {"efficientvit-b1-r224": 400,
+                          "efficientvit-b2-r224": 600}[arch]
+
+
+def test_int8_plan_tiles_n_then_k_when_the_weight_is_too_large():
+    budget = ops._DWCONV_VMEM_BYTES
+    # a 4096 x 11008 weight (45 MB) cannot sit whole in VMEM: N tiles in
+    # multiples of 128 that divide it, K stays whole while it fits
+    bm, bn, bk = ops.int8_tile_plan(64, 4096, 11008, 2)
+    assert bk == 4096 and bn < 11008 and bn % 128 == 0 and 11008 % bn == 0
+    assert bm == 64
+    assert ops._int8_tile_bytes(bm, bn, bk, 2) <= budget
+    # K too long for even a 128-wide N block: K tiles as well
+    bm, bn, bk = ops.int8_tile_plan(8, 50000, 50000, 4)
+    assert bn == 128 and bk < 50000 and bk % 128 == 0
+    assert ops._int8_tile_bytes(bm, bn, bk, 4) <= budget
+    # a smaller budget tiles what the default keeps whole
+    assert ops.int8_tile_plan(784, 384, 1536, 2)[1:] == (1536, 384)
+    small = ops.int8_tile_plan(784, 384, 1536, 2, budget=1 << 20)
+    assert small[1] < 1536 and small[1] % 128 == 0
+    assert ops._int8_tile_bytes(*small, 2) <= 1 << 20
+
+
+def test_int8_tiled_plan_runs_the_multi_k_path():
+    """A plan that tiles K accumulates over K steps in VMEM scratch and
+    matches the slab launch of the same product bit for bit."""
+    rng = _rng(5)
+    M, K, N = 32, 512, 384
+    qt = _mk_int8_weights(rng, K, N)
+    x = jnp.asarray(rng.normal(0, 1, (M, K)), jnp.float32)
+    sa = jnp.max(jnp.abs(x)) / 127.0
+    args = (x, qt.payload, sa, qt.scale.reshape(-1),
+            qt.zero_point.reshape(-1))
+    before = ops.int8_plan_counts()
+    slab = ops.int8_matmul_op(*args, interpret=True)
+    tiled = ops.int8_matmul_op(*args, interpret=True, blocks=(16, 128, 128))
+    after = ops.int8_plan_counts()
+    assert {k: after[k] - before[k] for k in after} == {"slab": 1,
+                                                        "tiled": 1}
+    np.testing.assert_array_equal(np.asarray(slab), np.asarray(tiled))
+
+
+def test_int8_plan_counts_every_b2_pointwise_conv_as_a_slab(pointwise):
+    launches, counts = pointwise["efficientvit-b2-r224"]
+    # 18 MBConvs x 2, 10 MSA blocks x 2 (qkv, proj), 2 in the head
+    assert len(launches) == 58
+    assert counts == {"slab": 58, "tiled": 0}
+
+
+# ---------------------------------------------------------------------------
 # autotuner
 # ---------------------------------------------------------------------------
 
 
 def test_autotune_interpret_falls_back_to_heuristic():
-    assert autotune.blocks_for("int8_matmul", 130, 258, 514,
+    assert autotune.blocks_for("m2q_matmul", 130, 258, 514,
                                interpret=True) == \
         autotune.heuristic_blocks(130, 258, 514)
     # no bench_fn -> heuristic even when "tunable"
+    assert autotune.blocks_for("m2q_matmul", 128, 128, 128,
+                               interpret=False) == (128, 128, 128)
+    # int8_matmul falls back to its VMEM slab plan instead (f32 unless the
+    # request says otherwise): whole K and N, all 130 rows
+    assert autotune.blocks_for("int8_matmul", 130, 258, 514,
+                               interpret=True) == (130, 258, 514) == \
+        ops.int8_tile_plan(130, 514, 258, 4)
     assert autotune.blocks_for("int8_matmul", 128, 128, 128,
                                interpret=False) == (128, 128, 128)
 
@@ -323,7 +467,7 @@ def test_autotune_cache_key_salts_backend_and_version():
     k_cpu = autotune.cache_key("int8_matmul", 8, 16, 32, backend="cpu")
     k_tpu = autotune.cache_key("int8_matmul", 8, 16, 32, backend="tpu")
     assert k_cpu != k_tpu
-    assert k_cpu == "int8_matmul@v1:8x16x32:cpu"
+    assert k_cpu == "int8_matmul@v2:8x16x32:cpu"
     # dwconv_w4 was re-gridded (H-tiling) — its salt must be bumped so
     # whole-map-era caches orphan instead of mis-steering the new grid
     assert autotune.KERNEL_VERSIONS["dwconv_w4"] >= 2

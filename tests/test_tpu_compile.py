@@ -1,7 +1,7 @@
 """Compile every Pallas kernel of the serving path for a TPU v5e, without one.
 
-Each case lowers a kernel entry point at the shapes EfficientViT-B1 R224
-(bucket 16) or qwen1.5-0.5b (decode batch 4) really produce, compiles it
+Each case lowers a kernel entry point at the shapes EfficientViT-B1 or -B2
+R224 (bucket 16) or qwen1.5-0.5b (decode batch 4) really produce, compiles it
 against a described ``v5e:2x2`` topology with ``interpret=False``, and asserts
 the compiled HLO launches the kernel (``tpu_custom_call``).  This is what the
 chip's compiler would refuse: blocks that break the (8, 128) tiling rule,
@@ -111,6 +111,38 @@ def test_kernel_compiles_for_v5e(kernel, fn, shapes, one_chip,
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
     assert kernel_counts(text) == {kernel: 1}
+
+
+# EfficientViT-B2 R224's int8 1x1 convs and head at bucket 16, (M, K, N):
+# the row slabs of whole K and N that int8_tile_plan gives, bf16 in and out
+# — where a VMEM overflow of the plan would show
+B2_POINTWISE = [(200704, 24, 96), (200704, 96, 24), (50176, 48, 192),
+                (50176, 192, 48), (50176, 48, 96), (12544, 96, 384),
+                (12544, 384, 96), (12544, 96, 192), (3136, 192, 768),
+                (3136, 192, 576), (3136, 768, 192), (3136, 384, 192),
+                (784, 384, 1536), (784, 384, 1152), (784, 1536, 384),
+                (784, 768, 384), (16, 1536, 1000)]
+
+
+@pytest.mark.parametrize("M,K,N", B2_POINTWISE,
+                         ids=[f"{m}x{k}x{n}" for m, k, n in B2_POINTWISE])
+def test_b2_int8_slab_plan_compiles_for_v5e(M, K, N, one_chip,
+                                            no_persistent_cache):
+    bm, bn, bk = ops.int8_tile_plan(M, K, N, 2)
+    assert (bn, bk) == (N, K) and M % bm == 0
+    bf16 = jnp.bfloat16
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in [((M, K), bf16), ((K, N), i8), ((), f32),
+                          ((N,), f32), ((N,), f32)]]
+    out = jax.eval_shape(lambda *a: ops.int8_matmul_op(*a, interpret=False),
+                         *args)
+    assert out.dtype == bf16 and out.shape == (M, N)
+    text = jax.jit(lambda *a: ops.int8_matmul_op(*a, interpret=False)).lower(
+        *args).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert kernel_counts(text) == {"int8_matmul": 1}
+    # the rows divide into whole slabs and K, N are whole: nothing padded
+    assert " pad(" not in text
 
 
 def test_b1_served_forward_compiles_with_every_kernel(one_chip,
